@@ -255,6 +255,9 @@ def kernels_micro():
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer repeats (smoke-level timing noise)")

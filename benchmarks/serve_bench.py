@@ -548,6 +548,9 @@ def bench_int4_kv(eng8: Engine, *, requests, prompt_len, gen):
 
 
 def main():
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m",
                     help="architecture preset to benchmark")

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Iterator
 
-import jax
+from jax.extend import core as jcore
 
 
 def subjaxprs(eqn) -> list:
@@ -25,12 +25,12 @@ def subjaxprs(eqn) -> list:
     out = []
     for val in eqn.params.values():
         for item in (val if isinstance(val, (list, tuple)) else [val]):
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jcore.ClosedJaxpr):
                 out.append(item.jaxpr)
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jcore.Jaxpr):
                 out.append(item)
             elif hasattr(item, "jaxpr") and isinstance(
-                    getattr(item, "jaxpr", None), jax.core.Jaxpr):
+                    getattr(item, "jaxpr", None), jcore.Jaxpr):
                 out.append(item.jaxpr)
     return out
 
@@ -38,7 +38,7 @@ def subjaxprs(eqn) -> list:
 def iter_eqns(jaxpr) -> Iterator:
     """Depth-first walk over every equation, recursing into subjaxprs.
     Accepts a Jaxpr or ClosedJaxpr."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -93,7 +93,7 @@ def producer_map(jaxpr) -> dict:
     block.  Backward walks (quantize-ancestry) stay within a block:
     values entering a block are parameters/consts there, which is exactly
     the conservative boundary we want."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out = {}
     for eqn in jaxpr.eqns:
@@ -122,7 +122,7 @@ def ancestor_prims(var, producers: dict, *, max_depth: int = 12) -> set:
             for sub in subjaxprs(eqn):
                 seen_prims.update(e.primitive.name for e in iter_eqns(sub))
             for iv in eqn.invars:
-                if not isinstance(iv, jax.core.Var) or iv in seen_vars:
+                if not isinstance(iv, jcore.Var) or iv in seen_vars:
                     continue
                 seen_vars.add(iv)
                 nxt.append(iv)
@@ -135,7 +135,7 @@ def ancestor_prims(var, producers: dict, *, max_depth: int = 12) -> set:
 def blocks(jaxpr) -> Iterator:
     """Every jaxpr block in the graph (the top jaxpr plus each subjaxpr),
     for analyses that need per-block producer maps."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     yield jaxpr
     for eqn in jaxpr.eqns:

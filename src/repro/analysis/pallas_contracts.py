@@ -62,6 +62,8 @@ import ast
 from pathlib import Path
 from typing import Optional, Sequence
 
+from jax.experimental import pallas as pl
+
 from repro.analysis.jaxprs import eqn_location, iter_eqns
 from repro.analysis.report import Finding
 
@@ -77,10 +79,12 @@ def _block_mapping_findings(eqn, gm, entry_point: str) -> list[Finding]:
     loc = eqn_location(eqn)
     want_arity = len(gm.grid) + gm.num_index_operands
     for bi, bm in enumerate(gm.block_mappings):
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         block = bm.block_shape
         for dim, (b, a) in enumerate(zip(block, arr.shape)):
-            if b is None or not isinstance(b, int):
+            if isinstance(b, pl.Blocked):
+                b = b.block_size
+            if not isinstance(b, int):
                 continue  # squeezed / element-indexed dims
             if b <= 0 or a % b:
                 out.append(Finding(
@@ -108,21 +112,21 @@ def _block_mapping_findings(eqn, gm, entry_point: str) -> list[Finding]:
 def _packing_findings(eqn, gm, entry_point: str) -> list[Finding]:
     """dp = D / (8 / kv_bits): int pools must be exactly D or D/2 wide."""
     in_maps = gm.block_mappings[:gm.num_inputs]
-    q_widths = [bm.array_shape_dtype.shape[-1] for bm in in_maps
-                if len(bm.array_shape_dtype.shape) == 4
-                and str(bm.array_shape_dtype.dtype) not in _INT_POOL_DTYPES
-                and "float" in str(bm.array_shape_dtype.dtype)
-                or len(bm.array_shape_dtype.shape) == 4
-                and str(bm.array_shape_dtype.dtype) == "bfloat16"]
+    q_widths = [bm.array_aval.shape[-1] for bm in in_maps
+                if len(bm.array_aval.shape) == 4
+                and str(bm.array_aval.dtype) not in _INT_POOL_DTYPES
+                and "float" in str(bm.array_aval.dtype)
+                or len(bm.array_aval.shape) == 4
+                and str(bm.array_aval.dtype) == "bfloat16"]
     pools = [bm for bm in in_maps
-             if len(bm.array_shape_dtype.shape) == 4
-             and str(bm.array_shape_dtype.dtype) in _INT_POOL_DTYPES]
+             if len(bm.array_aval.shape) == 4
+             and str(bm.array_aval.dtype) in _INT_POOL_DTYPES]
     if not q_widths or not pools:
         return []
     d = max(q_widths)  # the query head dim (logical width)
     out = []
     for bm in pools:
-        dp = bm.array_shape_dtype.shape[-1]
+        dp = bm.array_aval.shape[-1]
         if dp not in (d, d // 2 if d % 2 == 0 else -1):
             out.append(Finding(
                 analyzer="pallas_contracts", code="pallas.int4-packing",

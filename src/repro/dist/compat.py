@@ -1,34 +1,19 @@
-"""jax version compatibility shims for mesh / shard_map APIs.
-
-The repo targets current jax, but the pinned container ships an older
-release where ``axis_types`` / ``jax.shard_map`` don't exist yet.  These
-wrappers accept the modern call shape and degrade gracefully.
-"""
+"""Mesh / shard_map wrappers with the call shape the repo uses."""
 from __future__ import annotations
 
 import jax
 
 
 def make_mesh(shape, axis_names, *, devices=None):
-    """jax.make_mesh with auto axis_types when the version supports it."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    try:
-        axis_type = jax.sharding.AxisType.Auto
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type,) * len(axis_names), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axis_names, **kw)
+    """jax.make_mesh with every axis ``Auto`` (GSPMD-propagated)."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map (new) or jax.experimental.shard_map (old), with
-    replication checking off (collectives here are intentionally uneven)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """jax.shard_map with replication checking off (collectives here are
+    intentionally uneven)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -83,9 +83,26 @@ def constrain_activation(x: jax.Array, *, carry: bool = False) -> jax.Array:
         spec = P(rules.act_batch, seq, None)
     else:
         return x
-    try:
-        sharding = jax.sharding.NamedSharding(mesh, spec)
-        return jax.lax.with_sharding_constraint(x, sharding)
-    except Exception:
-        # axis not in this mesh / indivisible dim: placement is best-effort
+    if not _applies(spec, x.shape, mesh):
         return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.NamedSharding(mesh, spec))
+
+
+def _applies(spec, shape, mesh) -> bool:
+    """True when every axis ``spec`` names is in ``mesh`` and divides its
+    dim.  The rules are written for the production meshes; a smaller mesh
+    (no ``pod`` axis) or a batch below the data-axis size skips the
+    constraint; any other failure to apply it raises."""
+    for dim, entry in zip(shape, spec):
+        axes = entry if isinstance(entry, tuple) else (entry,)
+        size = 1
+        for a in axes:
+            if a is None:
+                continue
+            if a not in mesh.shape:
+                return False
+            size *= mesh.shape[a]
+        if dim % size:
+            return False
+    return True

@@ -27,25 +27,37 @@ is always data, never shape.
 
 Grid layout
 -----------
-``(B, KV-heads, S/block_s)`` with the sequence dimension innermost and
-declared "arbitrary" (B and head axes are "parallel"): sequential
-execution along the KV axis is what lets the (G, D) accumulator tile live
-in VMEM scratch across sequence steps — the partial-max/partial-sum
-combine of flash-decode.  Per-head dequant scales fold into q (keys) and
-the epilogue (values), so dequantization costs one scalar multiply per
-tile element, on the VPU, overlapping the MXU contraction.
+``(B, S/block_s)`` with the sequence dimension innermost and declared
+"arbitrary" (B is "parallel"): sequential execution along the sequence
+axis is what lets the accumulators live in VMEM scratch across sequence
+steps — the partial-max/partial-sum combine of flash-decode.  Each step
+DMAs one ``(1, block_s, KV, D)`` tile holding EVERY KV head and loops
+over the heads in the kernel body (a static unroll).  The head axis is
+not a grid axis because Mosaic only accepts a block whose last two dims
+are (8, 128)-aligned or span the array: a one-head ``(1, block_s, 1, D)``
+block over the ``(pages, block_s, KV, D)`` pool is neither, so every
+layout of repro.cache keeps its storage order and the kernel takes the
+heads together.  Per-head dequant scales fold into q (keys) and the
+epilogue (values), so dequantization costs one scalar multiply per tile
+element, on the VPU, overlapping the MXU contraction.
+
+Scales and positions
+--------------------
+The (KV,) scales and the (B,) ``cur_pos`` vector sit whole in SMEM and
+are read as scalars (``ks_ref[h]``, ``pos_ref[b]``): a ``(1, 1)`` VMEM
+block over a ``(KV, 1)`` array breaks the same block-shape rule.
 
 VMEM scratch expectations
 -------------------------
-Three scratch buffers persist across the innermost grid axis: the (G, D)
-f32 output accumulator plus (G, 1) running max and normalizer.  They are
-(re)initialized at ``si == 0`` and flushed to the output ref at
-``si == n_s - 1`` — correctness relies on the innermost axis running
-in-order on one core, which the "arbitrary" dimension semantics
-guarantee.  Budget: one (1, block_s, 1, D) int8 K tile + V tile are
-resident per step alongside the scratch; block_s is chosen so a whole
-tile fits comfortably (default 128 x D; the paged layout uses its page
-size).
+Three scratch buffers persist across the innermost grid axis: the
+(KV, G, D) f32 output accumulator plus (KV, G, 1) running max and
+normalizer.  They are (re)initialized at ``si == 0`` and flushed to the
+output ref at ``si == n_s - 1`` — correctness relies on the innermost
+axis running in-order on one core, which the "arbitrary" dimension
+semantics guarantee.  Budget: one K tile + V tile of
+(block_s, KV, D) int8 are resident per step alongside the scratch;
+block_s is chosen so a whole tile fits comfortably (default 128; the
+paged layout uses its page size).
 
 Masking semantics
 -----------------
@@ -72,16 +84,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_int4
-from repro.kernels.tpu_compat import tpu_compiler_params
 
 NEG_INF = -1e30
+# f32 MXU passes for the score and value dots, so the kernel computes
+# what its f32 oracle (kernels/ref.py) computes: with Mosaic's default,
+# one bf16 pass, the output is 2.2e-3 of max |output| off the oracle on
+# a v5e, with these passes 7e-7.  The extra passes' time is not measured
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
-                l_ref, si, *, block_s: int, dim: int, kv_bits: int):
-    """One online-softmax tile update (shared by the normalized kernel
-    and the sequence-parallel partials kernel — same math up to, but not
-    including, the epilogue)."""
+                l_ref, *, bi, si, block_s: int, dim: int, kv_bits: int):
+    """One online-softmax tile update for every KV head of the tile
+    (shared by the normalized kernel and the sequence-parallel partials
+    kernel — same math up to, but not including, the epilogue)."""
 
     @pl.when(si == 0)
     def _init():
@@ -89,44 +105,47 @@ def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # fold the key dequant scale and 1/sqrt(D) into q: per-head scales are
-    # uniform within the head, so (q*c) @ k_int8 == c * (q @ k)
-    c = ks_ref[0, 0] * jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
-    q = q_ref[0, 0].astype(jnp.float32) * c          # (G, D)
-    k = k_ref[0, :, 0, :]                            # (bs, D) — D/2 packed
-    if kv_bits == 4:
-        # the ONE extra op of the int4 lane: nibbles -> int8 in VMEM,
-        # before the f32 cast the int8 path already does.  Scales carry
-        # T/7 instead of T/127, so the fold below is unchanged.
-        k = unpack_int4(k, axis=-1)
-    k = k.astype(jnp.float32)                        # (bs, D) dequant-free
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                # (G, bs)
-
-    # mask the unwritten tail (cache slots >= this row's cur_pos); pos_ref
-    # is blocked per batch row, so slot-ragged positions mask per slot.
-    # k_pos is the LOGICAL position — the block table only moves storage
+    # mask the unwritten tail (cache slots >= this row's cur_pos), per
+    # batch row so slot-ragged positions mask per slot.  k_pos is the
+    # LOGICAL position — the block table only moves storage
     k_pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
-    valid = k_pos < pos_ref[0, 0]
-    s = jnp.where(valid, s, NEG_INF)
+    valid = k_pos < pos_ref[bi]
+    inv_sqrt_d = jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
 
-    m_prev = m_ref[...]                              # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    # re-mask: an all-masked tile has s == m_new == NEG_INF and exp(0) == 1
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)    # (G, bs)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    v = v_ref[0, :, 0, :]                            # (bs, D)
-    if kv_bits == 4:
-        v = unpack_int4(v, axis=-1)
-    v = v.astype(jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    for h in range(q_ref.shape[1]):
+        # fold the key dequant scale and 1/sqrt(D) into q: per-head
+        # scales are uniform within the head, so (q*c) @ k_int8 ==
+        # c * (q @ k)
+        q = q_ref[0, h].astype(jnp.float32) * (ks_ref[h] * inv_sqrt_d)
+        k = k_ref[0, :, h, :]                        # (bs, D) — D/2 packed
+        if kv_bits == 4:
+            # the ONE extra op of the int4 lane: nibbles -> int8 in
+            # VMEM, before the f32 cast the int8 path already does.
+            # Scales carry T/7 instead of T/127, so the fold is unchanged.
+            k = unpack_int4(k, axis=-1)
+        k = k.astype(jnp.float32)                    # (bs, D) dequant-free
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_F32,
+        )                                            # (G, bs)
+        s = jnp.where(valid, s, NEG_INF)
+
+        m_prev = m_ref[h]                            # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        # re-mask: an all-masked tile has s == m_new == NEG_INF and
+        # exp(0) == 1
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (G, bs)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, :, h, :]                        # (bs, D)
+        if kv_bits == 4:
+            v = unpack_int4(v, axis=-1)
+        v = v.astype(jnp.float32)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_F32,
+        )
+        m_ref[h] = m_new
 
 
 def _kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref, o_ref,
@@ -135,15 +154,17 @@ def _kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref, o_ref,
     # tab_ref is the scalar-prefetch block table: consumed by the K/V
     # index maps (page steering), never by the compute body
     del tab_ref
-    si = pl.program_id(2)
+    si = pl.program_id(1)
     _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
-                l_ref, si, block_s=block_s, dim=dim, kv_bits=kv_bits)
+                l_ref, bi=pl.program_id(0), si=si, block_s=block_s,
+                dim=dim, kv_bits=kv_bits)
 
     @pl.when(si == n_s - 1)
     def _epilogue():
         # value dequant folds once into the epilogue (linear in v)
-        o = acc_ref[...] * vs_ref[0, 0] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+        for h in range(o_ref.shape[1]):
+            o = acc_ref[h] * vs_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = o.astype(o_ref.dtype)
 
 
 def _partials_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref,
@@ -156,15 +177,17 @@ def _partials_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref,
     merge (repro.shard.partial_softmax.sp_partial_combine) produces the
     exact unsharded softmax from the gathered (m, l, acc) triples."""
     del tab_ref
-    si = pl.program_id(2)
+    si = pl.program_id(1)
     _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
-                l_ref, si, block_s=block_s, dim=dim, kv_bits=kv_bits)
+                l_ref, bi=pl.program_id(0), si=si, block_s=block_s,
+                dim=dim, kv_bits=kv_bits)
 
     @pl.when(si == n_s - 1)
     def _epilogue():
-        oa_ref[0, 0] = (acc_ref[...] * vs_ref[0, 0]).astype(oa_ref.dtype)
-        om_ref[0, 0] = m_ref[...].astype(om_ref.dtype)
-        ol_ref[0, 0] = l_ref[...].astype(ol_ref.dtype)
+        for h in range(oa_ref.shape[1]):
+            oa_ref[0, h] = (acc_ref[h] * vs_ref[h]).astype(oa_ref.dtype)
+        om_ref[0] = m_ref[...].astype(om_ref.dtype)
+        ol_ref[0] = l_ref[...].astype(ol_ref.dtype)
 
 
 @functools.partial(
@@ -203,40 +226,21 @@ def decode_attention_tiles(
                                kv_bits=kv_bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, n_s),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, h, si, tab: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda bi, h, si, tab: (tab[bi, si], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda bi, h, si, tab: (tab[bi, si], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (bi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, h, si, tab: (bi, h, 0, 0)),
-        scratch_shapes=_scratch(g, d),
+        grid=(b, n_s),
+        in_specs=_in_specs(kvh, g, d, bs, dp),
+        out_specs=_head_block(kvh, g, d),
+        scratch_shapes=_scratch(kvh, g, d),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), out_dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(
-        block_tab.astype(jnp.int32),
-        q,
-        k_pool,
-        v_pool,
-        k_scale.reshape(kvh, 1).astype(jnp.float32),
-        v_scale.reshape(kvh, 1).astype(jnp.float32),
-        # per-batch-row valid-slot count (prefill's kv_len pattern): a
-        # scalar broadcasts to all rows, a (B,) vector is slot-ragged
-        jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1),
-                         (b,)).reshape(b, 1),
-    )
+        name="decode_attention",
+    )(block_tab.astype(jnp.int32), q, k_pool, v_pool,
+      *_smem_operands(k_scale, v_scale, cur_pos, b))
 
 
 @functools.partial(
@@ -293,11 +297,35 @@ def decode_attention_int8(
         out_dtype=out_dtype, interpret=interpret, kv_bits=kv_bits)
 
 
-def _scratch(g, d):
+def _head_block(kvh, g, last):
+    """Per-batch-row block over a (B, KV, G, last) array: all heads."""
+    return pl.BlockSpec((1, kvh, g, last), lambda bi, si, tab: (bi, 0, 0, 0))
+
+
+def _in_specs(kvh, g, d, bs, dp):
+    """q block, K/V page tiles steered by the block table (all heads of
+    one page per step), and the three whole-array SMEM operands."""
+    kv_tile = pl.BlockSpec((1, bs, kvh, dp),
+                           lambda bi, si, tab: (tab[bi, si], 0, 0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return [_head_block(kvh, g, d), kv_tile, kv_tile, smem, smem, smem]
+
+
+def _smem_operands(k_scale, v_scale, cur_pos, b):
+    """(KV,) f32 scales and the per-batch-row valid-slot count (prefill's
+    kv_len pattern): a scalar ``cur_pos`` broadcasts to all rows, a (B,)
+    vector is slot-ragged."""
+    return (k_scale.reshape(-1).astype(jnp.float32),
+            v_scale.reshape(-1).astype(jnp.float32),
+            jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1),
+                             (b,)))
+
+
+def _scratch(kvh, g, d):
     return [
-        pltpu.VMEM((g, d), jnp.float32),  # output accumulator
-        pltpu.VMEM((g, 1), jnp.float32),  # running max
-        pltpu.VMEM((g, 1), jnp.float32),  # running normalizer
+        pltpu.VMEM((kvh, g, d), jnp.float32),  # output accumulator
+        pltpu.VMEM((kvh, g, 1), jnp.float32),  # running max
+        pltpu.VMEM((kvh, g, 1), jnp.float32),  # running normalizer
     ]
 
 
@@ -335,26 +363,11 @@ def decode_attention_partials_tiles(
                                dim=d, kv_bits=kv_bits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, n_s),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, h, si, tab: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda bi, h, si, tab: (tab[bi, si], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda bi, h, si, tab: (tab[bi, si], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, si, tab: (bi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda bi, h, si, tab: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1),
-                         lambda bi, h, si, tab: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1),
-                         lambda bi, h, si, tab: (bi, h, 0, 0)),
-        ],
-        scratch_shapes=_scratch(g, d),
+        grid=(b, n_s),
+        in_specs=_in_specs(kvh, g, d, bs, dp),
+        out_specs=[_head_block(kvh, g, d), _head_block(kvh, g, 1),
+                   _head_block(kvh, g, 1)],
+        scratch_shapes=_scratch(kvh, g, d),
     )
     acc, m, l = pl.pallas_call(
         kernel,
@@ -364,19 +377,12 @@ def decode_attention_partials_tiles(
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(
-        block_tab.astype(jnp.int32),
-        q,
-        k_pool,
-        v_pool,
-        k_scale.reshape(kvh, 1).astype(jnp.float32),
-        v_scale.reshape(kvh, 1).astype(jnp.float32),
-        jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1),
-                         (b,)).reshape(b, 1),
-    )
+        name="decode_attention_partials",
+    )(block_tab.astype(jnp.int32), q, k_pool, v_pool,
+      *_smem_operands(k_scale, v_scale, cur_pos, b))
     return acc, m[..., 0], l[..., 0]
 
 

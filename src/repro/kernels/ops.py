@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode
-(Python emulation of the kernel body) — correctness-identical to the TPU
-path, validated against the pure-jnp oracles in ref.py.  On a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` (default when a TPU backend is detected).
+Interpret mode follows the backend and nothing else: on a TPU the
+kernels compile through Mosaic; on any other backend (the CPU test runs)
+they execute in ``interpret=True`` mode (Python emulation of the kernel
+body), validated against the pure-jnp oracles in ref.py.  There is no
+override, so a TPU run can never emulate its kernels by accident.
 
 ``fake_quant`` carries the STE custom_vjp (paper eqs. 16-19): forward runs
 the fused Pallas kernel, backward computes
@@ -15,7 +16,6 @@ around it).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,9 +28,6 @@ from repro.kernels import ref as _ref
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

@@ -27,23 +27,27 @@ and passes the identity table — one kernel body for every layout.
 
 Grid layout
 -----------
-``(B, KV-heads, Q-chunks, KV-chunks)`` with the KV axis innermost and
-declared "arbitrary" (the three outer axes are "parallel"): in-order
-execution along KV is what lets the (block_q * G, D) accumulator tile
+``(B, Q-chunks, KV-chunks)`` with the KV axis innermost and declared
+"arbitrary" (the two outer axes are "parallel"): in-order execution
+along KV is what lets the per-head (block_q * G, D) accumulator tiles
 live in VMEM scratch across KV steps — classic flash-attention online
-softmax.  GQA groups are flattened into the query-row axis so every
-kernel tile is a plain 2D matmul operand.
+softmax.  Each step DMAs one ``(1, block_k, KV, D)`` tile holding every
+KV head and loops over the heads in the body (static unroll), as the
+decode kernel does and for the same reason: Mosaic refuses a one-head
+``(1, block_k, 1, D)`` block over the ``(pages, block_k, KV, D)`` pool.
+The (KV,) scales sit whole in SMEM.  GQA groups are flattened into the
+query-row axis so every kernel tile is a plain 2D matmul operand.
 
 VMEM scratch expectations
 -------------------------
 Three scratch buffers persist across the innermost (KV) axis: the
-(block_q * G, D) f32 output accumulator plus (block_q * G, 1) running max
-and normalizer.  They are (re)initialized at ``ki == 0`` and flushed at
-``ki == n_k - 1``, so correctness relies on the KV axis running in-order
-on one core (the "arbitrary" dimension contract).  Per step the resident
-set adds one (block_k, D) int8 K tile and V tile; the default 256-row
-blocks keep q-tile + scratch + K/V tiles within VMEM at head dims <= 256
-(paged pools use their page size as block_k).
+(KV, block_q * G, D) f32 output accumulator plus (KV, block_q * G, 1)
+running max and normalizer.  They are (re)initialized at ``ki == 0`` and
+flushed at ``ki == n_k - 1``, so correctness relies on the KV axis
+running in-order on one core (the "arbitrary" dimension contract).  Per
+step the resident set adds one (block_k, KV, D) int8 K tile and V tile;
+the default 256-row blocks fit VMEM at the served configs' head counts
+and dims (paged pools use their page size as block_k).
 
 Masking semantics
 -----------------
@@ -88,9 +92,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_int4
-from repro.kernels.tpu_compat import tpu_compiler_params
 
 NEG_INF = -1e30
+# f32 MXU passes for the score and value dots, so the kernel computes
+# what its f32 oracle (kernels/ref.py) computes: with Mosaic's default,
+# one bf16 pass, the output is 4.4e-3 of max |output| off the oracle on
+# a v5e, with these passes 5e-7.  The extra passes' time is not measured
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(tab_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -103,8 +111,8 @@ def _kernel(tab_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     # maps (the DMA-skip clamp) and read per batch row here.
     del tab_ref
     bi = pl.program_id(0)
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -128,22 +136,6 @@ def _kernel(tab_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(live)
     def _tile():
-        # fold key dequant scale and 1/sqrt(D) into q: the scale is uniform
-        # within a head, so (q*c) @ k_int8 == c * (q @ k)
-        c = ks_ref[0, 0] * jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
-        q = q_ref[0, 0].astype(jnp.float32) * c          # (block_q*G, D)
-        k = k_ref[0, :, 0, :]                            # (block_k, D)
-        if kv_bits == 4:
-            # int4 lane: one nibble unpack in VMEM before the f32 cast
-            # the int8 path already pays; scales carry T/7, so the
-            # q-fold above is unchanged
-            k = unpack_int4(k, axis=-1)
-        k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # (block_q*G, block_k)
-
         # element masks: GQA groups are flattened into rows, so row r is
         # query position q_lo + r // G
         rows = block_q * groups
@@ -154,30 +146,49 @@ def _kernel(tab_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             valid &= k_pos <= q_pos
         if window is not None:
             valid &= (q_pos - k_pos) < window
-        s = jnp.where(valid, s, NEG_INF)
+        inv_sqrt_d = jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
 
-        m_prev = m_ref[...]                              # (rows, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        # re-mask: an all-masked row has s == m_new == NEG_INF and exp(0) == 1
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0, :]                            # (block_k, D)
-        if kv_bits == 4:
-            v = unpack_int4(v, axis=-1)
-        v = v.astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        for h in range(q_ref.shape[1]):
+            # fold key dequant scale and 1/sqrt(D) into q: the scale is
+            # uniform within a head, so (q*c) @ k_int8 == c * (q @ k)
+            q = q_ref[0, h].astype(jnp.float32) * (ks_ref[h] * inv_sqrt_d)
+            k = k_ref[0, :, h, :]                        # (block_k, D)
+            if kv_bits == 4:
+                # int4 lane: one nibble unpack in VMEM before the f32
+                # cast the int8 path already pays; scales carry T/7, so
+                # the q-fold above is unchanged
+                k = unpack_int4(k, axis=-1)
+            k = k.astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_F32,
+            )                                            # (rows, block_k)
+            s = jnp.where(valid, s, NEG_INF)
+
+            m_prev = m_ref[h]                            # (rows, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # re-mask: an all-masked row has s == m_new == NEG_INF and
+            # exp(0) == 1
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, :, h, :]                        # (block_k, D)
+            if kv_bits == 4:
+                v = unpack_int4(v, axis=-1)
+            v = v.astype(jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_F32,
+            )
+            m_ref[h] = m_new
 
     @pl.when(ki == n_k - 1)
     def _epilogue():
         # value dequant folds once into the epilogue (linear in v); rows
         # with no visible key (padding / ragged tail) have l == 0 -> zeros
-        o = acc_ref[...] * vs_ref[0, 0] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+        for h in range(o_ref.shape[1]):
+            o = acc_ref[h] * vs_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = o.astype(o_ref.dtype)
 
 
 def _fit_block(s: int, target: int) -> int:
@@ -244,7 +255,7 @@ def prefill_attention_tiles(
         _kernel, n_k=n_k, block_q=bq, block_k=bk, groups=g, dim=d,
         causal=causal, window=window, kv_bits=kv_bits)
 
-    def kv_index(bi, h, qi, ki, tab, qs, kl):
+    def kv_index(bi, qi, ki, tab, qs, kl):
         if dma_skip:
             # clamp a fully-masked block to the nearest LIVE block: its
             # page index then repeats a neighbouring grid step's, so the
@@ -260,31 +271,27 @@ def prefill_attention_tiles(
             if window is not None:
                 first = jnp.maximum(0, (q_lo - (window - 1)) // bk)
             ki = jnp.clip(ki, first, last)
-        return (tab[bi, ki], 0, h, 0)
+        return (tab[bi, ki], 0, 0, 0)
 
+    q_block = pl.BlockSpec((1, kvh, rows, d),
+                           lambda bi, qi, ki, tab, qs, kl: (bi, 0, qi, 0))
+    kv_tile = pl.BlockSpec((1, bk, kvh, dp), kv_index)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda bi, h, qi, ki, tab, qs, kl: (bi, h, qi, 0)),
-            pl.BlockSpec((1, bk, 1, dp), kv_index),
-            pl.BlockSpec((1, bk, 1, dp), kv_index),
-            pl.BlockSpec((1, 1), lambda bi, h, qi, ki, tab, qs, kl: (h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, qi, ki, tab, qs, kl: (h, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, d),
-            lambda bi, h, qi, ki, tab, qs, kl: (bi, h, qi, 0)),
-        scratch_shapes=_scratch(rows, d),
+        grid=(b, n_q, n_k),
+        in_specs=[q_block, kv_tile, kv_tile, smem, smem],
+        out_specs=q_block,
+        scratch_shapes=_scratch(kvh, rows, d),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, sq_p * g, d), out_dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="prefill_attention",
     )(
         block_tab.astype(jnp.int32),
         jnp.broadcast_to(jnp.asarray(q_start, jnp.int32).reshape(-1), (b,)),
@@ -292,8 +299,8 @@ def prefill_attention_tiles(
         q2,
         k_pool,
         v_pool,
-        k_scale.reshape(kvh, 1).astype(jnp.float32),
-        v_scale.reshape(kvh, 1).astype(jnp.float32),
+        k_scale.reshape(kvh).astype(jnp.float32),
+        v_scale.reshape(kvh).astype(jnp.float32),
     )
     out = out.reshape(b, kvh, sq_p, g, d).transpose(0, 2, 1, 3, 4)
     return out[:, :sq]
@@ -344,9 +351,9 @@ def prefill_attention_int8(
         interpret=interpret, dma_skip=dma_skip, kv_bits=kv_bits)
 
 
-def _scratch(rows, d):
+def _scratch(kvh, rows, d):
     return [
-        pltpu.VMEM((rows, d), jnp.float32),  # output accumulator
-        pltpu.VMEM((rows, 1), jnp.float32),  # running max
-        pltpu.VMEM((rows, 1), jnp.float32),  # running normalizer
+        pltpu.VMEM((kvh, rows, d), jnp.float32),  # output accumulator
+        pltpu.VMEM((kvh, rows, 1), jnp.float32),  # running max
+        pltpu.VMEM((kvh, rows, 1), jnp.float32),  # running normalizer
     ]

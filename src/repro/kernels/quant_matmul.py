@@ -26,9 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_int4
-from repro.kernels.tpu_compat import tpu_compiler_params
 
 
 def _kernel(x_ref, w_ref, sw_ref, sa_ref, o_ref, acc_ref, *, n_k: int,
@@ -84,10 +84,11 @@ def quant_matmul(
 ):
     """Fused quantize -> int8 matmul -> dequant.
 
-    K and N (weight dims) must tile evenly — they are config-sized and
-    chosen MXU-aligned.  M is the token dim and ragged at decode (M = B*1);
-    it is padded up to a sublane-aligned tile and the pad rows sliced off,
-    so the same kernel serves prefill (M large) and decode (M = 1..8).
+    K and N (weight dims) tile by ``_weight_tile``: lane-aligned divisors
+    up to ``block_k``/``block_n``, else the whole dim.  M is the token dim
+    and ragged at decode (M = B*1); it is padded up to a sublane-aligned
+    tile and the pad rows sliced off, so the same kernel serves prefill
+    (M large) and decode (M = 1..8).
 
     ``w_bits == 4``: weights arrive nibble-packed along K (pack_int4 on
     axis 0 — half the resident weight bytes), quantized to ±7 with the
@@ -113,10 +114,7 @@ def quant_matmul(
     m = -(-m_aligned // bm) * bm
     if m != m0:
         x = jnp.pad(x, [(0, m - m0), (0, 0)])
-    bn, bk = min(block_n, n), min(block_k, k)
-    assert n % bn == 0 and k % bk == 0, (
-        f"weight dims (K={k}, N={n}) not tiled by (bk={bk}, bn={bn})"
-    )
+    bn, bk = _weight_tile(n, block_n), _weight_tile(k, block_k)
     # packed weights halve the K BlockSpec dim: tile kk covers logical
     # rows [kk*bk, (kk+1)*bk) either way, so the index map is unchanged
     bkw = bk // 2 if w_bits == 4 else bk
@@ -138,15 +136,21 @@ def quant_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_vmem_scratch(bm, bn)],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="quant_matmul",
     )(x, w_q, w_scale.reshape(1, n).astype(jnp.float32),
       jnp.reshape(act_scale, (1, 1)).astype(jnp.float32))[:m0]
 
 
-def _vmem_scratch(bm, bn):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM((bm, bn), jnp.int32)
+def _weight_tile(dim: int, cap: int) -> int:
+    """Tile for a weight dim (K or N): the largest multiple of the lane
+    width (128) that divides ``dim`` and is <= ``cap``; where none exists
+    (576 = 4.5 * 128, 192) the whole dim, which Mosaic always accepts as
+    a block extent."""
+    for t in range(cap // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim

@@ -88,6 +88,9 @@ class GenerationResult:
     decode_s: float            # decode wall time (gen - 1 steps)
     prompt_tokens: int
     gen_tokens: int
+    # the compiled executables that ran ("prefill", "decode"): their
+    # ``as_text()`` says which kernels the step really used
+    executables: dict = dataclasses.field(default_factory=dict)
 
 
 class Engine:
@@ -409,12 +412,12 @@ class Engine:
                 tokens[:, :prompt_len].astype(jnp.int32))
             hist = hist.at[:, prompt_len].set(next_tok)
             pos_v = jnp.full((requests,), pos0, jnp.int32)
-            loop_x = decode_loop.lower(self.serve_params, self.qparams,
-                                       next_tok, cache, pos_v, key,
-                                       hist).compile()
+            decode_x = decode_loop.lower(self.serve_params, self.qparams,
+                                         next_tok, cache, pos_v, key,
+                                         hist).compile()
             t0 = time.time()
-            out, cache = loop_x(self.serve_params, self.qparams, next_tok,
-                                cache, pos_v, key, hist)
+            out, cache = decode_x(self.serve_params, self.qparams, next_tok,
+                                  cache, pos_v, key, hist)
         else:
             decode_loop = jax.jit(
                 ST.make_decode_loop(model, cfg, policy, mode=mode,
@@ -422,17 +425,18 @@ class Engine:
                                     temperature=self.temperature,
                                     top_p=self.top_p),
                 donate_argnums=(3,))
-            loop_x = decode_loop.lower(self.serve_params, self.qparams,
-                                       next_tok, cache, pos0, key).compile()
+            decode_x = decode_loop.lower(self.serve_params, self.qparams,
+                                         next_tok, cache, pos0, key).compile()
             t0 = time.time()
-            out, cache = loop_x(self.serve_params, self.qparams, next_tok,
-                                cache, pos0, key)
+            out, cache = decode_x(self.serve_params, self.qparams, next_tok,
+                                  cache, pos0, key)
         out.block_until_ready()
         decode_s = time.time() - t0
         return GenerationResult(
             tokens=out, prefill_s=prefill_s, decode_s=decode_s,
             prompt_tokens=requests * prompt_len,
-            gen_tokens=int(out.shape[0]) * int(out.shape[1]))
+            gen_tokens=int(out.shape[0]) * int(out.shape[1]),
+            executables={"prefill": prefill_x, "decode": decode_x})
 
     # -- continuous batching -----------------------------------------------
     def make_scheduler(self, *, max_slots: int = 4, prompt_cap: int = 64,

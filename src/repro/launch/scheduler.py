@@ -36,12 +36,14 @@ and skipped-prefill counters the acceptance test pins.
 
 Resilience (fault isolation, deadlines, preemption, degradation)
 ----------------------------------------------------------------
-``run()`` never aborts because one request is bad.  Every request
-retires with a terminal ``Completion.status``:
+``run()`` never aborts because one request is bad (a fault of the
+server itself — a kernel that fails to compile, a device error — does
+propagate).  Every request retires with a terminal ``Completion.status``:
 
     ok         finished normally (``finished_by``: eos | budget | capacity)
     rejected   failed admission validation (never touched the device)
-    failed     prefill raised, or produced/decoded non-finite logits
+    failed     an injected admission fault, or non-finite prefill/decode
+               logits
     timeout    missed its ``deadline_ms`` (resident or still queued)
     preempted  evicted for a higher-priority request and the run ended
                before it could be re-admitted
@@ -811,10 +813,13 @@ class SlotScheduler:
                         continue
                     try:
                         t0, key = self._admit(slot, req)
-                    except Exception as e:  # noqa: BLE001 — isolation:
-                        # one bad admission (injected or real prefill
-                        # failure, non-finite logits) rejects THIS
-                        # request; the run keeps serving
+                    except (InjectedFault, FloatingPointError) as e:
+                        # isolation: a fault of THIS request (injected,
+                        # or non-finite prefill logits) retires it and
+                        # the run keeps serving.  Anything else — a
+                        # kernel that fails to lower or compile, a
+                        # device error — is the server's fault, not the
+                        # request's, and propagates
                         finish(req, [], "failed", status="failed",
                                reason=f"{type(e).__name__}: {e}")
                         continue

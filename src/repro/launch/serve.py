@@ -209,6 +209,9 @@ def run_continuous(args, engine: Engine):
 
 
 def main():
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m",
                     help="architecture preset to serve")

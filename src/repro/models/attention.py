@@ -530,12 +530,13 @@ class Attention(Module):
         # quantize once: the same tiles are appended AND (kernel path)
         # attended — no bf16 K/V re-materialization between the two
         kq, vq = cache.ready(k, v)
+        use_kernel = (ctx is not None and ctx.policy.use_pallas
+                      and self.causal)
         sp = _sp_info()
         if sp is not None:
             return self._sp_prefill(params, x, cache, ctx, q, k, v, kq, vq,
-                                    chunked, q_offset, kv_limit, sp)
-        use_kernel = (ctx is not None and ctx.policy.use_pallas
-                      and self.causal)
+                                    chunked, q_offset, kv_limit, sp,
+                                    use_kernel)
 
         if chunked:
             if cache.layout == "ring":
@@ -577,16 +578,7 @@ class Attention(Module):
             # paged scatters through the block table)
             new_cache = cache.append(kq, vq, 0)
             if use_kernel:
-                from repro.kernels import ops as kops
-
-                # attend the prompt's own cache-ready stream (identical
-                # tiles to what append just wrote — packed nibbles at
-                # bits=4, hence kv_bits from the cache)
-                o = kops.prefill_attention(
-                    q, kq, vq, *cache.scales(), jnp.int32(0),
-                    jnp.full((b,), s, jnp.int32), causal=True,
-                    window=self.window, kv_bits=cache.bits,
-                ).astype(x.dtype)
+                o = self._prompt_kernel_attention(q, kq, vq, cache, x.dtype)
             elif self.window is not None and s > self.window:
                 o = sliding_window_attention(q, k, v, window=self.window,
                                              q_chunk=self.q_chunk)
@@ -598,8 +590,22 @@ class Attention(Module):
         o = o.reshape(b, s, self.n_heads * self.head_dim)
         return self.wo(params["wo"], o, ctx), new_cache
 
+    def _prompt_kernel_attention(self, q, kq, vq, cache, dtype):
+        """One-shot prompt attention through the fused prefill kernel,
+        over the prompt's own cache-ready stream (identical tiles to what
+        append writes — packed nibbles at bits=4, hence kv_bits from the
+        cache)."""
+        from repro.kernels import ops as kops
+
+        b, s = q.shape[:2]
+        return kops.prefill_attention(
+            q, kq, vq, *cache.scales(), jnp.int32(0),
+            jnp.full((b,), s, jnp.int32), causal=True,
+            window=self.window, kv_bits=cache.bits,
+        ).astype(dtype)
+
     def _sp_prefill(self, params, x, cache, ctx, q, k, v, kq, vq, chunked,
-                    q_offset, kv_limit, sp):
+                    q_offset, kv_limit, sp, use_kernel):
         """Sequence-parallel prefill: each shard keeps the cache rows it
         owns (repro.shard.seq_cache owner writes — drop-mode scatters,
         never clamping slices).  One-shot chunks attend their own K/V
@@ -629,7 +635,11 @@ class Attention(Module):
                                 window=self.window).astype(x.dtype)
         else:
             new_cache = seq_cache.owner_append(cache, kq, vq, 0, sp.axis)
-            if self.window is not None and s > self.window:
+            if use_kernel:
+                # every shard holds the whole prompt: attend it exactly as
+                # one chip does, so sharded and unsharded prefill agree
+                o = self._prompt_kernel_attention(q, kq, vq, cache, x.dtype)
+            elif self.window is not None and s > self.window:
                 o = sliding_window_attention(q, k, v, window=self.window,
                                              q_chunk=self.q_chunk)
             else:

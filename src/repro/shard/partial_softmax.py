@@ -77,9 +77,15 @@ def sp_partial_combine(m, l, acc, axis_name: str):
     slot) return exact zeros.
     """
     # float payloads — gathers, not reduces; see module docstring
-    mg = jax.lax.all_gather(m, axis_name)        # (sp, B, KV, G, 1)
-    lg = jax.lax.all_gather(l, axis_name)
-    ag = jax.lax.all_gather(acc, axis_name)      # (sp, B, KV, G, 1, D)
+    return merge_partials(jax.lax.all_gather(m, axis_name),
+                          jax.lax.all_gather(l, axis_name),
+                          jax.lax.all_gather(acc, axis_name))
+
+
+def merge_partials(mg, lg, ag):
+    """The merge itself, over partials stacked on a leading shard axis:
+    mg/lg (sp, B, KV, G, 1), ag (sp, B, KV, G, 1, D).  Returns
+    ``sp_partial_combine``'s (B, 1, KV, G, D) f32."""
     m_tot = jnp.max(mg, axis=0)
     # NEG_INF is a finite sentinel: an all-empty row has m_i == M, the
     # weights come out exp(0) == 1, and the l_i == 0 terms still produce

@@ -236,7 +236,7 @@ class TestPallasContracts:
 
     def test_int4_packing_red_stub(self):
         def bm(shape, dt):
-            return NS(array_shape_dtype=NS(shape=shape, dtype=np.dtype(dt)))
+            return NS(array_aval=NS(shape=shape, dtype=np.dtype(dt)))
 
         # pool width 12 against head dim 16: neither D nor D/2
         gm = NS(block_mappings=[bm((2, 16, 3, 16), "float32"),

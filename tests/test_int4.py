@@ -313,7 +313,7 @@ class TestInt4KernelParity:
 
     def test_quant_matmul_int4_weights_match_oracle(self):
         rng = np.random.default_rng(5)
-        m, k, n = 16, 64, 16
+        m, k, n = 16, 256, 16
         x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
         w_raw = jnp.asarray(rng.integers(-7, 8, size=(k, n)), jnp.int8)
         w_q = pack_int4(w_raw, axis=0)          # (k//2, n) packed bytes
@@ -321,7 +321,7 @@ class TestInt4KernelParity:
                               jnp.float32)
         act_scale = jnp.float32(127.0 / 3.0)
         got = ops.quant_matmul(x, w_q, w_scale, act_scale, w_bits=4,
-                               block_m=16, block_n=16, block_k=32,
+                               block_m=16, block_n=16, block_k=128,
                                out_dtype=jnp.float32)
         want = kref.quant_matmul_ref(x, w_q, w_scale, act_scale,
                                      out_dtype=jnp.float32, w_bits=4)

@@ -30,8 +30,8 @@ class TestQuantMatmul:
     def test_matches_oracle(self, m, k, n, dtype):
         x, w_q, scale, act_scale = _mk(m, k, n, dtype)
         got = ops.quant_matmul(x, w_q, scale, act_scale,
-                               block_m=min(32, m), block_n=min(32, n),
-                               block_k=min(64, k), out_dtype=jnp.float32)
+                               block_m=min(32, m), block_n=min(128, n),
+                               block_k=min(128, k), out_dtype=jnp.float32)
         want = kref.quant_matmul_ref(x, w_q, scale, act_scale,
                                      out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -39,9 +39,9 @@ class TestQuantMatmul:
 
     def test_multi_k_step_accumulation(self):
         # K split across 4 grid steps exercises the VMEM accumulator path
-        x, w_q, scale, act_scale = _mk(16, 256, 16, jnp.float32, seed=3)
+        x, w_q, scale, act_scale = _mk(16, 512, 16, jnp.float32, seed=3)
         got = ops.quant_matmul(x, w_q, scale, act_scale,
-                               block_m=16, block_n=16, block_k=64,
+                               block_m=16, block_n=16, block_k=128,
                                out_dtype=jnp.float32)
         want = kref.quant_matmul_ref(x, w_q, scale, act_scale, jnp.float32)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

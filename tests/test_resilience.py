@@ -121,6 +121,22 @@ class TestIsolation:
         h = sched.health_stats()
         assert h["failed"] == 3 and h["ok"] == 1
 
+    def test_server_fault_during_admission_propagates(self, stack,
+                                                      monkeypatch):
+        """Only the request's own faults retire it as 'failed': a prefill
+        that cannot lower or compile is the server's fault and must not
+        pass for a served run with every status 'failed'."""
+        cfg, model, params, qp, policy, toks = stack
+        sched = _scheduler(model, cfg, policy, params, qp)
+
+        def broken(*args):
+            raise RuntimeError("Mosaic failed to compile the kernel")
+
+        monkeypatch.setattr(sched, "_prefill", broken)
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            sched.run([Request(rid=0, tokens=np.asarray(toks[0, :9]),
+                               max_gen=GEN)])
+
     def test_malformed_requests_rejected_not_raised(self, stack):
         cfg, model, params, qp, policy, toks = stack
         sched = _scheduler(model, cfg, policy, params, qp)

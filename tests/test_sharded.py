@@ -78,6 +78,23 @@ class TestTokenParity:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @needs2
+    def test_one_shot_prefill_decode_kernels(self):
+        """The fused-kernel path (the TPU default): sp=2 prefill attends
+        the prompt through the same prefill kernel as one device, and the
+        decode partials merge back to the unsharded kernel's tokens."""
+        from repro.launch.engine import Engine
+        from repro.shard.engine import ShardedEngine
+
+        kw = dict(cfg=_cfg(), smoke=True, cache_layout="dense",
+                  use_pallas=True)
+        base = Engine.from_checkpoint("smollm-135m", **kw)
+        sp2 = ShardedEngine.from_checkpoint("smollm-135m", sp=2, **kw)
+        batch = {"tokens": _toks(base.cfg)}
+        want = base.generate_batch(batch, GEN, prompt_len=S).tokens
+        got = sp2.generate_batch(batch, GEN, prompt_len=S).tokens
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @needs2
     @pytest.mark.parametrize("which", ["tp", "sp"])
     def test_scheduler_continuous_batching(self, engines, which):
         """Ragged admission through 2 slots: chunked prefill + slot

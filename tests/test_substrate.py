@@ -368,3 +368,33 @@ class TestConstrainActivation:
             np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))
         finally:
             constraints.install(prev)
+
+
+class TestCompileCache:
+    """The entry points' persistent compile cache: the environment's
+    directory when set (JAX reads it itself), else a fixed directory in
+    the checkout — never a per-run path, which would never hit."""
+
+    def _enable(self, monkeypatch, env):
+        from repro.launch import compile_cache
+
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        if env is None:
+            monkeypatch.delenv(compile_cache.ENV, raising=False)
+        else:
+            monkeypatch.setenv(compile_cache.ENV, env)
+        return compile_cache, compile_cache.enable(), updates
+
+    def test_environment_directory_is_left_to_jax(self, monkeypatch,
+                                                  tmp_path):
+        _, path, updates = self._enable(monkeypatch, str(tmp_path))
+        assert path == str(tmp_path) and updates == {}
+
+    def test_default_is_fixed_checkout_directory(self, monkeypatch):
+        cc, path, updates = self._enable(monkeypatch, None)
+        assert path == str(cc.CHECKOUT / ".jax_cache")
+        assert (cc.CHECKOUT / "chip_smoke.py").exists()
+        assert updates == {"jax_compilation_cache_dir": path}
+        assert self._enable(monkeypatch, None)[1] == path
