@@ -1,0 +1,6 @@
+"""decode_attention's share of its roofline in the traced decode blocks."""
+from bench import measure
+
+
+def read(run):
+    return measure.roofline_pct(run, "decode_attention", "decode")

@@ -1,0 +1,6 @@
+"""prefill_attention's share of its roofline in the traced admissions."""
+from bench import measure
+
+
+def read(run):
+    return measure.roofline_pct(run, "prefill_attention", "admit")
