@@ -16,7 +16,7 @@ Per cell this:
      collectives all fail HERE),
   5. prints memory_analysis() / cost_analysis(),
   6. derives the three roofline terms (compute/memory/collective) with
-     v5e constants and writes a JSON report for benchmarks/roofline.py.
+     v5e constants and writes a JSON report.
 
 Usage:
   python -m repro.launch.dryrun --arch mixtral-8x7b --shape train_4k

@@ -110,6 +110,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core import api as A
 from repro.launch import steps as ST
 from repro.launch import strategies as SG
+from repro.launch import telemetry as tel
 from repro.launch.faults import FaultPlan, InjectedFault, SimulatedCrash
 from repro.launch.journal import (RequestJournal, completion_from_dict,
                                   completion_to_dict, request_from_dict,
@@ -185,6 +186,11 @@ class _RunState:
     readmit: deque              # _Parked preemption victims
     vclock: float               # virtual ms when plan.ms_per_block > 0
     t_start: float              # wall-clock run origin
+    # rid -> host times (time.monotonic()) of t_ingest, t_admit, t_first,
+    # t_last: written into its sched.request span at retirement, and kept
+    # off Completion so that completions of a recovered run still compare
+    # equal to an uninterrupted run's
+    times: dict = dataclasses.field(default_factory=dict)
 
 
 def _cache_map(fn, *trees):
@@ -220,8 +226,8 @@ class SlotScheduler:
     gen_cap : per-slot generation headroom reserved in the cache.
     prefill_chunk : chunk size of the admission prefill scan; None picks
         ``max(8, min(16, prompt_cap))`` — the single home of that default
-        (serve.py and serve_bench.py both inherit it, so the benchmark
-        measures the executable the CLI serves).
+        (serve.py and the on-chip benchmark both inherit it, so the
+        benchmark measures the executable the CLI serves).
     block_steps : decode-block length; admission happens at block
         boundaries, so smaller blocks = lower admission latency, larger
         blocks = fewer dispatches.
@@ -422,6 +428,9 @@ class SlotScheduler:
             def wrapper(*args):
                 self._trace_counts[name] += 1
                 return fn(*args)
+            # jit names its program after the function: the profiler's
+            # module names read jit_sched_<piece>
+            wrapper.__name__ = wrapper.__qualname__ = f"sched_{name}"
             return wrapper
 
         self._prefill_fn = jax.jit(counted("prefill", ST.make_prefill_step(
@@ -620,7 +629,7 @@ class SlotScheduler:
             self._journal.begin(self._epoch, self._knobs())
             for req in rs.arrivals:
                 self._journal.enqueue(req)
-        return self._drive(max_blocks)
+        return self._drive(max_blocks, "run", t0=rs.t_start)
 
     def resume_run(self,
                    max_blocks: Optional[int] = None) -> list[Completion]:
@@ -634,10 +643,36 @@ class SlotScheduler:
             raise ValueError(
                 "no run state to resume (call load_state(), recover(), "
                 "or run() first)")
-        return self._drive(max_blocks)
+        return self._drive(max_blocks, "resume")
 
-    def _drive(self, max_blocks: Optional[int] = None) -> list[Completion]:
-        """The scheduler host loop over ``self._rs`` (see ``run``)."""
+    def _drive(self, max_blocks: Optional[int], entry: str,
+               t0: Optional[float] = None) -> list[Completion]:
+        """The scheduler host loop over ``self._rs`` (see ``run``), inside
+        one ``sched.run`` span (``t0``: the run's origin, for ``run()``).
+
+        Spans (``launch/telemetry.py``; docs/serving.md "Spans"): the
+        leaves tile the loop, so from the run's start to its end every
+        instant lies in exactly one of ``sched.loop`` (the iteration's
+        bookkeeping: deadlines, arrivals, preemption, choosing a waiter,
+        retirements), ``sched.wait`` (asleep until the next arrival), the
+        children of ``sched.admit``/``sched.readmit``, and the children of
+        ``sched.decode``: ``decode.transfer`` (host arrays to the device),
+        ``decode.call`` (the jitted block, until it returns),
+        ``decode.fetch`` (its outputs on the host), ``decode.collect``
+        (emissions and retirements) and ``decode.commit``
+        (``_boundary_commit``).  The decode spans sit here, around the
+        call site, not inside ``_decode``: a caller that replaces
+        ``_decode`` with a wrapper that waits for the block (a benchmark
+        harness) moves the device time into ``decode.call``, and
+        ``decode.fetch`` is then a pure copy; unwrapped, ``decode.call``
+        is the dispatch and ``decode.fetch`` holds the wait.  Each
+        retirement writes a ``sched.request`` span from the request's due
+        time to its retirement, with its host times as attributes."""
+        with tel.span("sched.run", t0=t0, slots=self.max_slots,
+                      mode=self.mode, entry=entry):
+            return self._loop(max_blocks)
+
+    def _loop(self, max_blocks: Optional[int]) -> list[Completion]:
         plan = self._plan
         B = self.max_slots
         rs = self._rs
@@ -657,6 +692,16 @@ class SlotScheduler:
                 self._health[why] += 1
             if self._journal is not None:
                 self._journal.retire(c)
+            t1 = time.monotonic()
+            times = rs.times.pop(req.rid, {})
+            # due on the host clock; under a virtual clock arrivals follow
+            # virtual ms, so the request is due when it was noticed
+            due = (rs.t_start + req.arrive_ms * 1e-3
+                   if plan.ms_per_block <= 0 else times.get("t_ingest", t1))
+            tel.record("sched.request", due, t1, rid=req.rid, status=status,
+                       n_tokens=len(out), **{
+                           k: times.get(k) for k in
+                           ("t_ingest", "t_admit", "t_first", "t_last")})
 
         def retire(slot: int, why: str, status: str = "ok",
                    reason: Optional[str] = None):
@@ -718,6 +763,8 @@ class SlotScheduler:
 
         def ingest():
             while rs.arrivals and rs.arrivals[0].arrive_ms <= now_ms():
+                rs.times.setdefault(rs.arrivals[0].rid, {}).setdefault(
+                    "t_ingest", time.monotonic())
                 if (self.queue_cap is not None
                         and len(rs.pending) >= self.queue_cap):
                     if self.shed_policy == "shed":
@@ -791,7 +838,8 @@ class SlotScheduler:
             rs.slot_steps[slot] = steps
             self._slot_keys[slot] = key
 
-        def admit_free_slots():
+        def admit_free_slots(loop: tel.Span):
+            # each admission pauses the sched.loop span around it
             for slot in range(B):
                 if rs.slot_req[slot] is not None:
                     continue
@@ -800,8 +848,9 @@ class SlotScheduler:
                     if item is None:
                         return
                     if isinstance(item, _Parked):
-                        self._readmit(slot, item.req, item.out,
-                                      recovered=item.recovered)
+                        with loop.paused():
+                            self._readmit(slot, item.req, item.out,
+                                          recovered=item.recovered)
                         seed_host_state(slot, item.req, item.out,
                                         item.key, item.steps)
                         break
@@ -812,7 +861,8 @@ class SlotScheduler:
                                reason=err)
                         continue
                     try:
-                        t0, key = self._admit(slot, req)
+                        with loop.paused():
+                            t0, key = self._admit(slot, req)
                     except (InjectedFault, FloatingPointError) as e:
                         # isolation: a fault of THIS request (injected,
                         # or non-finite prefill logits) retires it and
@@ -831,121 +881,151 @@ class SlotScheduler:
                     break
 
         while rs.arrivals or rs.pending or rs.readmit or rs.active.any():
-            reap_deadlines()
-            ingest()
-            force_preempts()
-            priority_preempt()
-            admit_free_slots()
-            if not rs.active.any():
-                if rs.arrivals and not rs.pending and not rs.readmit:
+            with tel.span("sched.loop") as loop:
+                reap_deadlines()
+                ingest()
+                force_preempts()
+                priority_preempt()
+                admit_free_slots(loop)
+                n_active = int(rs.active.sum())
+                idle = n_active == 0
+                wait = idle and bool(rs.arrivals) and not rs.pending \
+                    and not rs.readmit
+                if wait and plan.ms_per_block > 0:
                     # nothing runnable until the next arrival: advance
                     # the clock to it instead of spinning
-                    if plan.ms_per_block > 0:
-                        rs.vclock = max(rs.vclock,
-                                        rs.arrivals[0].arrive_ms)
-                    else:
-                        time.sleep(min(
-                            1e-3, max(0.0, (rs.arrivals[0].arrive_ms
-                                            - now_ms()) * 1e-3)))
+                    rs.vclock = max(rs.vclock, rs.arrivals[0].arrive_ms)
+            if idle:
+                if wait and plan.ms_per_block <= 0:
+                    # nothing runnable, nothing to reap: sleep to the next
+                    # arrival
+                    with tel.span("sched.wait"):
+                        time.sleep(max(0.0, (rs.arrivals[0].arrive_ms
+                                             - now_ms()) * 1e-3))
                 continue
 
             # -- one decode block over the slot batch ----------------------
-            # nan_step: per-slot in-block scan step at which a scheduled
-            # decode fault fires (-1 = none) — data, not shape
-            nan_step = np.full((B,), -1, np.int32)
-            for slot in range(B):
-                req = rs.slot_req[slot]
-                if req is None or not rs.active[slot]:
-                    continue
-                step = plan.nan_decode_step(req.rid)
-                if step is not None:
-                    rel = step - rs.slot_steps[slot]
-                    if 0 <= rel < self.block_steps:
-                        nan_step[slot] = rel
-            ran = rs.active.copy()
-            toks, emitted, self._cache, pos_d, active_d, keys_d, hist, \
-                bad_d = self._decode(
-                    self.serve_params, self.qparams,
-                    jnp.asarray(rs.last_tok),
-                    self._cache, jnp.asarray(rs.pos),
-                    jnp.asarray(rs.active),
-                    jnp.asarray(self._slot_keys), jnp.asarray(self._hist),
-                    jnp.asarray(nan_step))
-            toks = np.asarray(toks)
-            emitted = np.asarray(emitted)
-            pos_new = np.asarray(pos_d)
-            active_new = np.asarray(active_d)
-            bad = np.asarray(bad_d)
-            # host copies: admission mutates rows in place (np.asarray of
-            # a device buffer is read-only)
-            self._hist = np.array(hist)
-            self._slot_keys = np.array(keys_d)
-            for slot in range(B):
-                if ran[slot]:
-                    rs.slot_steps[slot] += self.block_steps
-            if self._emit_w > 1:
-                # a window with any emission ran a live verify pass
-                win = emitted.reshape(B, self.block_steps, self._emit_w)
-                self._spec_windows += int(win.any(-1).sum())
-                self._spec_emitted += int(emitted.sum())
-
-            # -- collect emissions, retire finished slots ------------------
-            # emission lanes are RAGGED within a speculative window (a
-            # partial accept leaves un-emitted tail lanes, then the next
-            # window emits again) — skip gaps instead of stopping at one
-            for slot in range(B):
-                req = rs.slot_req[slot]
-                if req is None or not rs.active[slot]:
-                    continue
-                for i in range(self.block_steps * self._emit_w):
-                    if len(rs.slot_out[slot]) >= req.max_gen:
-                        break
-                    if not emitted[slot, i]:
-                        continue
-                    rs.slot_out[slot].append(int(toks[slot, i]))
-                rs.pos[slot] = pos_new[slot]
-                rs.last_tok[slot] = (rs.slot_out[slot][-1]
-                                     if rs.slot_out[slot]
-                                     else rs.last_tok[slot])
-                # finish reason from what was actually COLLECTED: an EOS
-                # beyond the budget cut was never part of the output, so
-                # that request finished by budget, not eos — and a
-                # device-side freeze without a collected EOS and with
-                # budget to spare can only be the NaN guard (flagged in
-                # ``bad``) or the capacity guard
-                hit_eos = (self.eos_id >= 0 and bool(rs.slot_out[slot])
-                           and rs.slot_out[slot][-1] == self.eos_id)
-                budget_done = len(rs.slot_out[slot]) >= req.max_gen
-                if hit_eos:
-                    retire(slot, "eos")
-                elif budget_done:
-                    retire(slot, "budget")
-                elif bad[slot]:
-                    retire(slot, "failed", status="failed",
-                           reason="non-finite logits during decode")
-                elif not active_new[slot]:
-                    retire(slot, "capacity")
-                else:
-                    rs.active[slot] = active_new[slot]
-            rs.n_blocks += 1
-            if plan.ms_per_block > 0:
-                rs.vclock += plan.ms_per_block
-            # -- boundary commit: WAL flush, snapshot cadence, crash ------
-            self._boundary_commit(rs, now_ms())
+            with tel.span("sched.decode", block=rs.n_blocks,
+                          active=n_active) as block:
+                with tel.span("decode.transfer"):
+                    # nan_step: per-slot in-block scan step at which a
+                    # scheduled decode fault fires (-1 = none) — data, not
+                    # shape
+                    nan_step = np.full((B,), -1, np.int32)
+                    for slot in range(B):
+                        req = rs.slot_req[slot]
+                        if req is None or not rs.active[slot]:
+                            continue
+                        step = plan.nan_decode_step(req.rid)
+                        if step is not None:
+                            rel = step - rs.slot_steps[slot]
+                            if 0 <= rel < self.block_steps:
+                                nan_step[slot] = rel
+                    ran = rs.active.copy()
+                    args = (self.serve_params, self.qparams,
+                            jnp.asarray(rs.last_tok), self._cache,
+                            jnp.asarray(rs.pos), jnp.asarray(rs.active),
+                            jnp.asarray(self._slot_keys),
+                            jnp.asarray(self._hist), jnp.asarray(nan_step))
+                with tel.span("decode.call"):
+                    toks, emitted, self._cache, pos_d, active_d, keys_d, \
+                        hist, bad_d = self._decode(*args)
+                    del args        # the cache argument is donated
+                with tel.span("decode.fetch") as fetch:
+                    toks = np.asarray(toks)
+                    emitted = np.asarray(emitted)
+                    pos_new = np.asarray(pos_d)
+                    active_new = np.asarray(active_d)
+                    bad = np.asarray(bad_d)
+                    # host copies: admission mutates rows in place
+                    # (np.asarray of a device buffer is read-only)
+                    self._hist = np.array(hist)
+                    self._slot_keys = np.array(keys_d)
+                with tel.span("decode.collect"):
+                    block.attrs["kept"] = self._collect(
+                        rs, ran, toks, emitted, pos_new, active_new, bad,
+                        fetch.t1, retire)
+                    rs.n_blocks += 1
+                    if plan.ms_per_block > 0:
+                        rs.vclock += plan.ms_per_block
+                # -- boundary commit: WAL flush, snapshot cadence, crash --
+                with tel.span("decode.commit"):
+                    self._boundary_commit(rs, now_ms())
             if max_blocks is not None and rs.n_blocks >= max_blocks:
                 break
-        # parked victims the run never got back to are terminal too —
-        # with their generated-so-far tokens, so nothing is silently lost
-        while rs.readmit:
-            p = rs.readmit.popleft()
-            finish(p.req, p.out, "preempted", status="preempted",
-                   reason="preempted; run ended before re-admission")
-        # no resident remains (or the run was cut): drop any prefix-store
-        # references this run's slots held so unused entries stay evictable
-        if self._prefix is not None:
-            for slot in range(B):
-                self._prefix.release(slot)
+        with tel.span("sched.loop"):
+            # parked victims the run never got back to are terminal too —
+            # with their generated-so-far tokens, so nothing is silently
+            # lost
+            while rs.readmit:
+                p = rs.readmit.popleft()
+                finish(p.req, p.out, "preempted", status="preempted",
+                       reason="preempted; run ended before re-admission")
+            # no resident remains (or the run was cut): drop any prefix-
+            # store references this run's slots held so unused entries
+            # stay evictable
+            if self._prefix is not None:
+                for slot in range(B):
+                    self._prefix.release(slot)
         return rs.done
+
+    def _collect(self, rs: _RunState, ran, toks, emitted, pos_new,
+                 active_new, bad, t_fetch: float, retire) -> int:
+        """Take one decode block's emissions into the run state and retire
+        the slots it finished; returns the tokens kept.  ``t_fetch``: when
+        the block's outputs reached the host (each request's t_last)."""
+        B = self.max_slots
+        for slot in range(B):
+            if ran[slot]:
+                rs.slot_steps[slot] += self.block_steps
+        if self._emit_w > 1:
+            # a window with any emission ran a live verify pass
+            win = emitted.reshape(B, self.block_steps, self._emit_w)
+            self._spec_windows += int(win.any(-1).sum())
+            self._spec_emitted += int(emitted.sum())
+
+        # emission lanes are RAGGED within a speculative window (a partial
+        # accept leaves un-emitted tail lanes, then the next window emits
+        # again) — skip gaps instead of stopping at one
+        kept = 0
+        for slot in range(B):
+            req = rs.slot_req[slot]
+            if req is None or not rs.active[slot]:
+                continue
+            had = len(rs.slot_out[slot])
+            for i in range(self.block_steps * self._emit_w):
+                if len(rs.slot_out[slot]) >= req.max_gen:
+                    break
+                if not emitted[slot, i]:
+                    continue
+                rs.slot_out[slot].append(int(toks[slot, i]))
+            if len(rs.slot_out[slot]) > had:
+                kept += len(rs.slot_out[slot]) - had
+                rs.times.setdefault(req.rid, {})["t_last"] = t_fetch
+            rs.pos[slot] = pos_new[slot]
+            rs.last_tok[slot] = (rs.slot_out[slot][-1]
+                                 if rs.slot_out[slot]
+                                 else rs.last_tok[slot])
+            # finish reason from what was actually COLLECTED: an EOS beyond
+            # the budget cut was never part of the output, so that request
+            # finished by budget, not eos — and a device-side freeze
+            # without a collected EOS and with budget to spare can only be
+            # the NaN guard (flagged in ``bad``) or the capacity guard
+            hit_eos = (self.eos_id >= 0 and bool(rs.slot_out[slot])
+                       and rs.slot_out[slot][-1] == self.eos_id)
+            budget_done = len(rs.slot_out[slot]) >= req.max_gen
+            if hit_eos:
+                retire(slot, "eos")
+            elif budget_done:
+                retire(slot, "budget")
+            elif bad[slot]:
+                retire(slot, "failed", status="failed",
+                       reason="non-finite logits during decode")
+            elif not active_new[slot]:
+                retire(slot, "capacity")
+            else:
+                rs.active[slot] = active_new[slot]
+        return kept
 
     def _boundary_commit(self, rs: _RunState, clock_ms: float):
         """Everything that makes a decode-block boundary DURABLE, in the
@@ -1044,7 +1124,7 @@ class SlotScheduler:
         self._rs = rs
         self._epoch = max(self._epoch, rp.epoch)
         self._rewrite_epoch(rs)
-        return self._drive(max_blocks)
+        return self._drive(max_blocks, "recover")
 
     def _rewrite_epoch(self, rs: _RunState):
         """Start a fresh journal epoch that re-states the surviving run
@@ -1256,43 +1336,62 @@ class SlotScheduler:
         registers the prompt for future sharers.  Raises on injected
         admission faults and non-finite prefill logits — BEFORE anything
         is spliced into the resident cache, so a failed admission leaves
-        no trace."""
+        no trace.
+
+        One ``sched.admit`` span, with a child per device round trip:
+        ``admit.keys`` (the request's PRNG keys, its prefix-store
+        lookup), ``admit.prefill`` (through the logits on the host),
+        ``admit.insert`` (the splice, or a hit's page attach) and
+        ``admit.first_token`` (through the token on the host)."""
         L = int(len(req.tokens))
-        if self._plan.rejects(req.rid):
-            raise InjectedFault(
-                f"request {req.rid}: injected admission failure")
-        k_t0, k_carry = self._request_keys(req.rid)
-        key = tuple(int(t) for t in np.asarray(req.tokens))
-
-        if self._prefix is not None:
-            entry = self._prefix.lookup(key, slot)
+        with tel.span("sched.admit", rid=req.rid, slot=slot,
+                      prompt_len=L) as admit:
+            times = self._rs.times.setdefault(req.rid, {})
+            times["t_admit"] = admit.t0
+            if self._plan.rejects(req.rid):
+                raise InjectedFault(
+                    f"request {req.rid}: injected admission failure")
+            with tel.span("admit.keys", rid=req.rid):
+                k_t0, k_carry = self._request_keys(req.rid)
+                key = tuple(int(t) for t in np.asarray(req.tokens))
+                entry = (self._prefix.lookup(key, slot)
+                         if self._prefix is not None else None)
+            admit.attrs["prefix_hit"] = entry is not None
             if entry is not None:
-                return self._attach_prefix(slot, entry, k_t0), k_carry
-
-        toks = np.zeros((1, self.prompt_cap), np.int32)
-        toks[0, :L] = np.asarray(req.tokens, np.int32)
-        lengths = jnp.asarray([L], jnp.int32)
-        logits, slot_cache = self._prefill(
-            self.serve_params, self.qparams, {"tokens": jnp.asarray(toks)},
-            self._slot_cache0, lengths)
-        last_row = np.asarray(logits)[:, -1, :]
-        if self._plan.nans_prefill(req.rid):
-            last_row = np.full_like(last_row, np.nan)
-        if not np.isfinite(last_row).all():
-            raise FloatingPointError(
-                f"request {req.rid}: non-finite prefill logits")
-        if self._prefix is None:
-            self._call_counts["insert"] += 1
-            self._cache = self._insert_fn(self._cache, slot_cache,
-                                          jnp.asarray(slot, jnp.int32))
-        else:
-            row = self._private_rows[slot]
-            self._call_counts["insert"] += 1
-            self._cache = self._insert_fn(self._cache, slot_cache,
-                                          jnp.asarray(row))
-            self._set_row(slot, row)
-            self._register_prefix(key, L, row, logits)
-        return self._sample_t0(logits, k_t0), k_carry
+                admit.attrs["chunks"] = 0
+                with tel.span("admit.insert", rid=req.rid):
+                    self._attach_prefix(slot, entry)
+                logits = entry.logits
+            else:
+                admit.attrs["chunks"] = self.prompt_cap // self.prefill_chunk
+                with tel.span("admit.prefill", rid=req.rid):
+                    toks = np.zeros((1, self.prompt_cap), np.int32)
+                    toks[0, :L] = np.asarray(req.tokens, np.int32)
+                    lengths = jnp.asarray([L], jnp.int32)
+                    logits, slot_cache = self._prefill(
+                        self.serve_params, self.qparams,
+                        {"tokens": jnp.asarray(toks)}, self._slot_cache0,
+                        lengths)
+                    last_row = np.asarray(logits)[:, -1, :]
+                    if self._plan.nans_prefill(req.rid):
+                        last_row = np.full_like(last_row, np.nan)
+                    if not np.isfinite(last_row).all():
+                        raise FloatingPointError(
+                            f"request {req.rid}: non-finite prefill logits")
+                with tel.span("admit.insert", rid=req.rid):
+                    self._insert(slot, slot_cache)
+                    if self._prefix is not None:
+                        self._register_prefix(key, L,
+                                               self._private_rows[slot],
+                                               logits)
+                    # release the batch-1 cache inside the admission's
+                    # spans, so its cost is timed as the admission's
+                    del slot_cache
+            with tel.span("admit.first_token", rid=req.rid) as first:
+                t0 = self._sample_t0(logits, k_t0)
+                del logits, k_t0
+            times["t_first"] = times["t_last"] = first.t1
+            return t0, k_carry
 
     def _readmit(self, slot: int, req: Request, out: list,
                  recovered: bool = False):
@@ -1305,31 +1404,42 @@ class SlotScheduler:
         includes generated tokens no other request shares).  Crash
         recovery rides the same executable (``recovered=True``) but
         counts re-prefilled tokens as ``replayed_tokens`` instead of a
-        preemption re-admission."""
+        preemption re-admission.  One ``sched.readmit`` span, with
+        children ``readmit.resume`` and ``readmit.insert``."""
         L = len(req.tokens)
         resume = L + len(out) - 1   # pending token is NOT yet in cache
-        toks = np.zeros((1, self._resume_cap), np.int32)
-        seq = list(np.asarray(req.tokens, np.int32)) + [int(t) for t in
-                                                        out[:-1]]
-        toks[0, :resume] = np.asarray(seq, np.int32)
-        lengths = jnp.asarray([resume], jnp.int32)
-        _, slot_cache = self._resume(
-            self.serve_params, self.qparams, {"tokens": jnp.asarray(toks)},
-            self._slot_cache0, lengths)
-        if self._prefix is None:
-            self._call_counts["insert"] += 1
-            self._cache = self._insert_fn(self._cache, slot_cache,
-                                          jnp.asarray(slot, jnp.int32))
-        else:
-            row = self._private_rows[slot]
-            self._call_counts["insert"] += 1
-            self._cache = self._insert_fn(self._cache, slot_cache,
-                                          jnp.asarray(row))
-            self._set_row(slot, row)
+        with tel.span("sched.readmit", rid=req.rid, slot=slot,
+                      prompt_len=L, resume_len=resume, recovered=recovered):
+            with tel.span("readmit.resume", rid=req.rid):
+                toks = np.zeros((1, self._resume_cap), np.int32)
+                seq = list(np.asarray(req.tokens, np.int32)) + [
+                    int(t) for t in out[:-1]]
+                toks[0, :resume] = np.asarray(seq, np.int32)
+                lengths = jnp.asarray([resume], jnp.int32)
+                _, slot_cache = self._resume(
+                    self.serve_params, self.qparams,
+                    {"tokens": jnp.asarray(toks)}, self._slot_cache0,
+                    lengths)
+            with tel.span("readmit.insert", rid=req.rid):
+                self._insert(slot, slot_cache)
+                del slot_cache
         if recovered:
             self._health["replayed_tokens"] += resume
         else:
             self._health["readmits"] += 1
+
+    def _insert(self, slot: int, slot_cache):
+        """Splice a batch-1 prefill result into ``slot``: its dense region,
+        or (paged) its private pages and their block-table row."""
+        self._call_counts["insert"] += 1
+        if self._prefix is None:
+            self._cache = self._insert_fn(self._cache, slot_cache,
+                                          jnp.asarray(slot, jnp.int32))
+        else:
+            row = self._private_rows[slot]
+            self._cache = self._insert_fn(self._cache, slot_cache,
+                                          jnp.asarray(row))
+            self._set_row(slot, row)
 
     # -- paged plumbing ----------------------------------------------------
     def _set_row(self, slot: int, row: np.ndarray):
@@ -1377,7 +1487,7 @@ class SlotScheduler:
             pages=pages, tail_page=tail, length=L,
             logits=np.asarray(logits)))
 
-    def _attach_prefix(self, slot: int, entry: PrefixEntry, k_t0) -> int:
+    def _attach_prefix(self, slot: int, entry: PrefixEntry):
         """Full-prompt hit: point the slot's table row at the shared
         pages; the partial tail page (decode's first append target) is
         copied into the slot's private page so shared pages stay
@@ -1389,4 +1499,3 @@ class SlotScheduler:
         if entry.tail_page is not None:
             self._copy_pages([(int(entry.tail_page),
                                int(self._private_rows[slot][n_full]))])
-        return self._sample_t0(entry.logits, k_t0)

@@ -15,8 +15,7 @@ through the cache's kernel view — an identity block table for the
 dense/ring layouts, the page table for ``--cache-layout paged``.  The
 decode loop is a single compiled ``jax.lax.scan`` over the generation
 (steps.make_decode_loop): N tokens cost one dispatch instead of N.
-``--loop`` keeps the legacy per-token Python loop for comparison
-(benchmarks/serve_bench.py tracks the ratio).
+``--loop`` keeps the legacy per-token Python loop for comparison.
 
 ``--prefill-chunk N`` switches prefill to the chunked ragged pipeline:
 one lax.scan over fixed-size prompt chunks plus a per-request length
@@ -75,6 +74,8 @@ Usage:
              serving fresh requests)
          --strict (exit non-zero when any request retires non-'ok' —
              lets chaos CI and scripts gate on degraded runs)
+         --spans PATH (write the run's scheduler spans as JSON lines at
+             the end — docs/serving.md "Spans")
          --ckpt-dir DIR (restore trained params instead of random init)
          --tp N / --sp N (sharded serving over N devices: tensor-
              parallel projections with int32 psum epilogues / sequence-
@@ -133,6 +134,7 @@ def run_continuous(args, engine: Engine):
     """--max-slots path: stream --requests ragged requests through the
     slot scheduler (or, with --restore, pick a crashed run back up from
     its journal/snapshot) and report aggregate throughput."""
+    from repro.launch import telemetry
     from repro.launch.faults import SimulatedCrash
 
     sched_kw = dict(max_slots=args.max_slots, prompt_cap=args.prompt_len,
@@ -159,6 +161,10 @@ def run_continuous(args, engine: Engine):
               "(+ --journal PATH) or --restore snapshot "
               "(+ --snapshot-dir DIR) to finish the run bit-identically")
         raise SystemExit(3)
+    finally:
+        if args.spans:
+            n = telemetry.tracer().write_jsonl(args.spans)
+            print(f"[serve] spans: {n} records -> {args.spans}")
     wall = time.time() - t0
     sched = engine.make_scheduler(**sched_kw)
     n_new = sum(len(c.tokens) for c in completions)
@@ -324,6 +330,11 @@ def main():
     ap.add_argument("--strict", action="store_true",
                     help="exit with code 1 if any request retires with a "
                          "non-'ok' terminal status (CI gating)")
+    ap.add_argument("--spans", default=None,
+                    help="write the scheduler's span records of the run "
+                         "(phases of the host loop, one timeline per "
+                         "request; launch/telemetry.py) to PATH as JSON "
+                         "lines when it ends, a crashed run included")
     ap.add_argument("--ckpt-dir", default=None,
                     help="restore trained params from a launch/train.py "
                          "checkpoint directory (default: random init)")
@@ -351,8 +362,8 @@ def main():
         ap.error("--mesh dryrun audits the sharded executables — give it "
                  "--tp N or --sp N")
     if (args.journal or args.snapshot_dir or args.restore
-            or args.strict) and not args.max_slots:
-        ap.error("--journal/--snapshot-dir/--restore/--strict need "
+            or args.strict or args.spans) and not args.max_slots:
+        ap.error("--journal/--snapshot-dir/--restore/--strict/--spans need "
                  "--max-slots (the continuous-batching scheduler)")
     if args.restore == "journal" and not args.journal:
         ap.error("--restore journal needs --journal PATH")
