@@ -41,23 +41,40 @@ heads together.  Per-head dequant scales fold into q (keys) and the
 epilogue (values), so dequantization costs one scalar multiply per tile
 element, on the VPU, overlapping the MXU contraction.
 
-Scales and positions
---------------------
-The (KV,) scales and the (B,) ``cur_pos`` vector sit whole in SMEM and
-are read as scalars (``ks_ref[h]``, ``pos_ref[b]``): a ``(1, 1)`` VMEM
-block over a ``(KV, 1)`` array breaks the same block-shape rule.
+Dead tiles: DMA clamp and compute skip
+--------------------------------------
+The block table and the (B,) ``cur_pos`` vector are the two scalar-
+prefetch operands, so the K/V index maps see each row's position.  A
+tile that starts at or past ``cur_pos[b]`` holds no live key.  Its index
+is clamped to the row's last live tile, ``max(ceil(cur_pos[b] /
+block_s) - 1, 0)``: the block index then repeats the previous grid
+step's and the pipeline elides the copy.  In the body, ``pl.when(si *
+block_s < cur_pos[b])`` skips the tile's compute, the same predicate.  A
+row costs the DMA and the MXU work of its live tiles only; a dead grid
+step is a fixed per-step overhead.  The table is read only at live
+entries, so a paged table may hold anything past a row's last live
+block.  Skipping changes no output bit: a fully masked tile would leave
+the running max, normalizer and accumulator as they were (``corr ==
+exp(0) == 1``, ``p == 0``).
+
+Scales
+------
+The (KV,) scales sit whole in SMEM and are read as scalars
+(``ks_ref[h]``): a ``(1, 1)`` VMEM block over a ``(KV, 1)`` array breaks
+the same block-shape rule.
 
 VMEM scratch expectations
 -------------------------
 Three scratch buffers persist across the innermost grid axis: the
 (KV, G, D) f32 output accumulator plus (KV, G, 1) running max and
 normalizer.  They are (re)initialized at ``si == 0`` and flushed to the
-output ref at ``si == n_s - 1`` — correctness relies on the innermost
-axis running in-order on one core, which the "arbitrary" dimension
-semantics guarantee.  Budget: one K tile + V tile of
+output ref at ``si == n_s - 1`` whether or not those tiles are live (a
+skipped tile leaves them untouched) — correctness relies on the
+innermost axis running in-order on one core, which the "arbitrary"
+dimension semantics guarantee.  Budget: one K tile + V tile of
 (block_s, KV, D) int8 are resident per step alongside the scratch;
-block_s is chosen so a whole tile fits comfortably (default 128; the
-paged layout uses its page size).
+block_s is chosen so a whole tile fits comfortably (``decode_block_s``,
+default 128; the paged layout uses its page size).
 
 Masking semantics
 -----------------
@@ -65,11 +82,12 @@ Masking semantics
 (uniform batch, the single-stream serving path) or a (B,) vector (the
 slot-based continuous-batching scheduler: each slot of the batch decodes
 at its own position).  Positions are LOGICAL (block index * block_s +
-offset) — the table only relocates storage.  Slots at ``k_pos >=
-cur_pos[b]`` are masked BEFORE the running-max update and re-masked after
-(an all-masked tile has s == m_new == NEG_INF and exp(0) == 1, which
-would corrupt l).  A row with ``cur_pos[b] == 0`` (inactive scheduler
-slot) masks every key and normalizes to exact zeros in the epilogue.
+offset) — the table only relocates storage.  Within the last live tile,
+slots at ``k_pos >= cur_pos[b]`` are masked BEFORE the running-max
+update and re-masked after.  A row with ``cur_pos[b] == 0`` (inactive
+scheduler slot) skips every tile, ends with ``acc == 0, l == 0`` and
+normalizes to exact zeros in the epilogue; the partials kernel returns
+``(0, NEG_INF, 0)`` for it, the merge identity.
 
 A bf16 cache runs through the same kernel with scales == 1.  The
 pure-jnp oracle is kernels/ref.py::decode_attention_ref.
@@ -93,6 +111,25 @@ NEG_INF = -1e30
 _F32 = jax.lax.Precision.HIGHEST
 
 
+def decode_block_s(s: int, block_s: int = 128) -> int:
+    """The sequence tile of the dense entry points over a cache of ``s``
+    positions: the largest multiple of 8 up to ``block_s`` that divides
+    ``s`` (8 if none does).  The scheduler counts live tiles with it."""
+    bs = max(8, min(block_s, s) // 8 * 8)
+    while bs > 8 and s % bs:
+        bs -= 8
+    return bs
+
+
+def _kv_index(bi, si, tab, pos, *, block_s: int, n_s: int):
+    """K/V index map: row ``bi``'s page for tile ``si``, clamped to the
+    row's last live tile ``max(ceil(pos / block_s) - 1, 0)``.  A dead
+    tile repeats the previous grid step's page, so its copy is elided,
+    and the table is never read past a row's live blocks."""
+    last = jnp.minimum(n_s - 1, (jnp.maximum(pos[bi], 1) - 1) // block_s)
+    return (tab[bi, jnp.minimum(si, last)], 0, 0, 0)
+
+
 def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
                 l_ref, *, bi, si, block_s: int, dim: int, kv_bits: int):
     """One online-softmax tile update for every KV head of the tile
@@ -105,50 +142,55 @@ def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # mask the unwritten tail (cache slots >= this row's cur_pos), per
-    # batch row so slot-ragged positions mask per slot.  k_pos is the
-    # LOGICAL position — the block table only moves storage
-    k_pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
-    valid = k_pos < pos_ref[bi]
-    inv_sqrt_d = jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
+    # a tile with no live key is skipped: its index map repeated the
+    # last live tile's, so its contents are not this tile's
+    @pl.when(si * block_s < pos_ref[bi])
+    def _tile():
+        # mask the unwritten tail (cache slots >= this row's cur_pos),
+        # per batch row so slot-ragged positions mask per slot.  k_pos
+        # is the LOGICAL position — the block table only moves storage
+        k_pos = si * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_s), 1)
+        valid = k_pos < pos_ref[bi]
+        inv_sqrt_d = jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
 
-    for h in range(q_ref.shape[1]):
-        # fold the key dequant scale and 1/sqrt(D) into q: per-head
-        # scales are uniform within the head, so (q*c) @ k_int8 ==
-        # c * (q @ k)
-        q = q_ref[0, h].astype(jnp.float32) * (ks_ref[h] * inv_sqrt_d)
-        k = k_ref[0, :, h, :]                        # (bs, D) — D/2 packed
-        if kv_bits == 4:
-            # the ONE extra op of the int4 lane: nibbles -> int8 in
-            # VMEM, before the f32 cast the int8 path already does.
-            # Scales carry T/7 instead of T/127, so the fold is unchanged.
-            k = unpack_int4(k, axis=-1)
-        k = k.astype(jnp.float32)                    # (bs, D) dequant-free
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_F32,
-        )                                            # (G, bs)
-        s = jnp.where(valid, s, NEG_INF)
+        for h in range(q_ref.shape[1]):
+            # fold the key dequant scale and 1/sqrt(D) into q: per-head
+            # scales are uniform within the head, so (q*c) @ k_int8 ==
+            # c * (q @ k)
+            q = q_ref[0, h].astype(jnp.float32) * (ks_ref[h] * inv_sqrt_d)
+            k = k_ref[0, :, h, :]                    # (bs, D) — D/2 packed
+            if kv_bits == 4:
+                # the ONE extra op of the int4 lane: nibbles -> int8 in
+                # VMEM, before the f32 cast the int8 path already does.
+                # Scales carry T/7 instead of T/127, so the fold is
+                # unchanged.
+                k = unpack_int4(k, axis=-1)
+            k = k.astype(jnp.float32)                # (bs, D) dequant-free
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_F32,
+            )                                        # (G, bs)
+            s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[h]                            # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        # re-mask: an all-masked tile has s == m_new == NEG_INF and
-        # exp(0) == 1
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (G, bs)
-        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, h, :]                        # (bs, D)
-        if kv_bits == 4:
-            v = unpack_int4(v, axis=-1)
-        v = v.astype(jnp.float32)
-        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_F32,
-        )
-        m_ref[h] = m_new
+            m_prev = m_ref[h]                        # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # re-mask: a masked key adds exactly 0 whatever m_new is
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (G, bs)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, :, h, :]                    # (bs, D)
+            if kv_bits == 4:
+                v = unpack_int4(v, axis=-1)
+            v = v.astype(jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_F32,
+            )
+            m_ref[h] = m_new
 
 
-def _kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref, o_ref,
+def _kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
             acc_ref, m_ref, l_ref, *, n_s: int, block_s: int, dim: int,
             kv_bits: int):
     # tab_ref is the scalar-prefetch block table: consumed by the K/V
@@ -167,7 +209,7 @@ def _kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref, o_ref,
             o_ref[0, h] = o.astype(o_ref.dtype)
 
 
-def _partials_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref,
+def _partials_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                      oa_ref, om_ref, ol_ref, acc_ref, m_ref, l_ref, *,
                      n_s: int, block_s: int, dim: int, kv_bits: int):
     """Sequence-parallel epilogue: emit the raw flash state (unnormalized
@@ -188,6 +230,45 @@ def _partials_kernel(tab_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, pos_ref,
             oa_ref[0, h] = (acc_ref[h] * vs_ref[h]).astype(oa_ref.dtype)
         om_ref[0] = m_ref[...].astype(om_ref.dtype)
         ol_ref[0] = l_ref[...].astype(ol_ref.dtype)
+
+
+def _launch(kernel, name, out_specs, out_shape, q, k_pool, v_pool,
+            block_tab, k_scale, v_scale, cur_pos, *, interpret, kv_bits):
+    """One decode launch over block-table-mapped tiles: grid, specs and
+    operands shared by the normalized and the partials kernel."""
+    b, kvh, g, d = q.shape
+    dp = k_pool.shape[-1]  # storage width (D, or D/2 packed)
+    assert dp * (2 if kv_bits == 4 else 1) == d, (
+        f"kv_bits={kv_bits}: pool head dim {dp} does not match q head "
+        f"dim {d}")
+    bs = k_pool.shape[1]
+    n_s = block_tab.shape[1]
+
+    kv_tile = pl.BlockSpec((1, bs, kvh, dp), functools.partial(
+        _kv_index, block_s=bs, n_s=n_s))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_s),
+        in_specs=[_head_block(kvh, g, d), kv_tile, kv_tile, smem, smem],
+        out_specs=out_specs,
+        scratch_shapes=_scratch(kvh, g, d),
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, n_s=n_s, block_s=bs, dim=d,
+                          kv_bits=kv_bits),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(block_tab.astype(jnp.int32),
+      # the per-row valid-key count: a scalar broadcasts to every row
+      jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1), (b,)),
+      q, k_pool, v_pool,
+      k_scale.reshape(-1).astype(jnp.float32),
+      v_scale.reshape(-1).astype(jnp.float32))
 
 
 @functools.partial(
@@ -215,32 +296,34 @@ def decode_attention_tiles(
     block table and index maps are UNCHANGED — they address blocks, not
     bytes; only the tile's last BlockSpec dim halves."""
     b, kvh, g, d = q.shape
-    dp = k_pool.shape[-1]  # storage width (D, or D/2 packed)
-    assert dp * (2 if kv_bits == 4 else 1) == d, (
-        f"kv_bits={kv_bits}: pool head dim {dp} does not match q head "
-        f"dim {d}")
-    bs = k_pool.shape[1]
-    n_s = block_tab.shape[1]
+    return _launch(
+        _kernel, "decode_attention", _head_block(kvh, g, d),
+        jax.ShapeDtypeStruct((b, kvh, g, d), out_dtype),
+        q, k_pool, v_pool, block_tab, k_scale, v_scale, cur_pos,
+        interpret=interpret, kv_bits=kv_bits)
 
-    kernel = functools.partial(_kernel, n_s=n_s, block_s=bs, dim=d,
-                               kv_bits=kv_bits)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_s),
-        in_specs=_in_specs(kvh, g, d, bs, dp),
-        out_specs=_head_block(kvh, g, d),
-        scratch_shapes=_scratch(kvh, g, d),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), out_dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention",
-    )(block_tab.astype(jnp.int32), q, k_pool, v_pool,
-      *_smem_operands(k_scale, v_scale, cur_pos, b))
+
+def _dense_pool(k_cache, v_cache, block_s: int):
+    """Contiguous (B, S, KV, D) caches as a page pool and its identity
+    block table: splitting the sequence axis into (blocks, block_s)
+    merges with batch into a page axis copy-free."""
+    b, s, kvh, d = k_cache.shape
+    # a tile that divides S exactly: a pad here copies the WHOLE cache
+    # every decode step (it cannot be hoisted out of a scanned decode
+    # loop), which would double the HBM traffic the int8 cache exists to
+    # halve.  The scheduler rounds the cache length to a block_s multiple
+    # for the kernel path; the pad fallback below only fires for odd
+    # ad-hoc lengths.
+    bs = decode_block_s(s, block_s)
+    s_pad = -(-s // bs) * bs
+    if s_pad != s:
+        pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
+        k_cache = jnp.pad(k_cache, pad)
+        v_cache = jnp.pad(v_cache, pad)
+    n_s = s_pad // bs
+    tab = jnp.arange(b * n_s, dtype=jnp.int32).reshape(b, n_s)
+    return (k_cache.reshape(b * n_s, bs, kvh, d),
+            v_cache.reshape(b * n_s, bs, kvh, d), tab)
 
 
 @functools.partial(
@@ -268,30 +351,7 @@ def decode_attention_int8(
     uniform single-stream path, a vector serves slot-ragged continuous
     batching, where a 0 entry marks an inactive slot (output zeros).
     """
-    b, kvh, g, d = q.shape
-    d = k_cache.shape[-1]  # storage width (packed bytes at kv_bits == 4)
-    s = k_cache.shape[1]
-    # prefer a sublane-aligned tile that divides S exactly: a pad here
-    # copies the WHOLE cache every decode step (it cannot be hoisted out
-    # of a scanned decode loop), which would double the HBM traffic the
-    # int8 cache exists to halve.  serve.py rounds the cache length to a
-    # block_s multiple for the kernel path; the pad fallback below only
-    # fires for odd ad-hoc lengths.
-    bs = max(8, min(block_s, s) // 8 * 8)
-    while bs > 8 and s % bs:
-        bs -= 8
-    s_pad = -(-s // bs) * bs
-    if s_pad != s:
-        pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
-        k_cache = jnp.pad(k_cache, pad)
-        v_cache = jnp.pad(v_cache, pad)
-    n_s = s_pad // bs
-
-    # identity view: splitting the contiguous sequence axis into
-    # (blocks, block_s) merges with batch into a page axis copy-free
-    k_pool = k_cache.reshape(b * n_s, bs, kvh, d)
-    v_pool = v_cache.reshape(b * n_s, bs, kvh, d)
-    tab = jnp.arange(b * n_s, dtype=jnp.int32).reshape(b, n_s)
+    k_pool, v_pool, tab = _dense_pool(k_cache, v_cache, block_s)
     return decode_attention_tiles(
         q, k_pool, v_pool, tab, k_scale, v_scale, cur_pos,
         out_dtype=out_dtype, interpret=interpret, kv_bits=kv_bits)
@@ -299,26 +359,8 @@ def decode_attention_int8(
 
 def _head_block(kvh, g, last):
     """Per-batch-row block over a (B, KV, G, last) array: all heads."""
-    return pl.BlockSpec((1, kvh, g, last), lambda bi, si, tab: (bi, 0, 0, 0))
-
-
-def _in_specs(kvh, g, d, bs, dp):
-    """q block, K/V page tiles steered by the block table (all heads of
-    one page per step), and the three whole-array SMEM operands."""
-    kv_tile = pl.BlockSpec((1, bs, kvh, dp),
-                           lambda bi, si, tab: (tab[bi, si], 0, 0, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return [_head_block(kvh, g, d), kv_tile, kv_tile, smem, smem, smem]
-
-
-def _smem_operands(k_scale, v_scale, cur_pos, b):
-    """(KV,) f32 scales and the per-batch-row valid-slot count (prefill's
-    kv_len pattern): a scalar ``cur_pos`` broadcasts to all rows, a (B,)
-    vector is slot-ragged."""
-    return (k_scale.reshape(-1).astype(jnp.float32),
-            v_scale.reshape(-1).astype(jnp.float32),
-            jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1),
-                             (b,)))
+    return pl.BlockSpec((1, kvh, g, last),
+                        lambda bi, si, tab, pos: (bi, 0, 0, 0))
 
 
 def _scratch(kvh, g, d):
@@ -354,35 +396,15 @@ def decode_attention_partials_tiles(
     f32).  The single-shard invariant ``acc / max(l, eps) ==
     decode_attention_tiles(...)`` is pinned in tests/test_sharded.py."""
     b, kvh, g, d = q.shape
-    dp = k_pool.shape[-1]
-    assert dp * (2 if kv_bits == 4 else 1) == d
-    bs = k_pool.shape[1]
-    n_s = block_tab.shape[1]
-
-    kernel = functools.partial(_partials_kernel, n_s=n_s, block_s=bs,
-                               dim=d, kv_bits=kv_bits)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_s),
-        in_specs=_in_specs(kvh, g, d, bs, dp),
-        out_specs=[_head_block(kvh, g, d), _head_block(kvh, g, 1),
-                   _head_block(kvh, g, 1)],
-        scratch_shapes=_scratch(kvh, g, d),
-    )
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kvh, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention_partials",
-    )(block_tab.astype(jnp.int32), q, k_pool, v_pool,
-      *_smem_operands(k_scale, v_scale, cur_pos, b))
+    acc, m, l = _launch(
+        _partials_kernel, "decode_attention_partials",
+        [_head_block(kvh, g, d), _head_block(kvh, g, 1),
+         _head_block(kvh, g, 1)],
+        [jax.ShapeDtypeStruct((b, kvh, g, d), jnp.float32),
+         jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
+         jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32)],
+        q, k_pool, v_pool, block_tab, k_scale, v_scale, cur_pos,
+        interpret=interpret, kv_bits=kv_bits)
     return acc, m[..., 0], l[..., 0]
 
 
@@ -403,21 +425,7 @@ def decode_attention_partials(
     """Dense entry point for the partials kernel (identity block table
     over the shard-local cache slice — same degenerate-table trick as
     ``decode_attention_int8``)."""
-    b, kvh, g, d = q.shape
-    d = k_cache.shape[-1]
-    s = k_cache.shape[1]
-    bs = max(8, min(block_s, s) // 8 * 8)
-    while bs > 8 and s % bs:
-        bs -= 8
-    s_pad = -(-s // bs) * bs
-    if s_pad != s:
-        pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
-        k_cache = jnp.pad(k_cache, pad)
-        v_cache = jnp.pad(v_cache, pad)
-    n_s = s_pad // bs
-    k_pool = k_cache.reshape(b * n_s, bs, kvh, d)
-    v_pool = v_cache.reshape(b * n_s, bs, kvh, d)
-    tab = jnp.arange(b * n_s, dtype=jnp.int32).reshape(b, n_s)
+    k_pool, v_pool, tab = _dense_pool(k_cache, v_cache, block_s)
     return decode_attention_partials_tiles(
         q, k_pool, v_pool, tab, k_scale, v_scale, cur_pos,
         interpret=interpret, kv_bits=kv_bits)

@@ -77,7 +77,8 @@ steps, so fully-dead tiles cost neither MXU time (``pl.when``, as
 before) nor DMA bandwidth.  The clamp predicate is exactly the kernel
 body's ``live`` predicate, so a clamped tile is never read.
 ``dma_skip=False`` keeps the unclamped maps (the parity oracle in
-tests/test_prefill_fastpath.py).
+tests/test_prefill_fastpath.py).  The decode kernel clamps and skips the
+same way from its per-row ``cur_pos``, always.
 
 A bf16/f32 K/V stream runs through the same kernel with scales == 1.
 The pure-jnp oracle is kernels/ref.py::prefill_attention_ref.

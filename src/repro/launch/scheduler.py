@@ -108,6 +108,7 @@ from repro.cache import (KVCache, PrefixEntry, PrefixStore, copy_pages,
                          set_table_row, splice_dense_into_pages)
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import api as A
+from repro.kernels.decode_attention import decode_block_s
 from repro.launch import steps as ST
 from repro.launch import strategies as SG
 from repro.launch import telemetry as tel
@@ -366,6 +367,11 @@ class SlotScheduler:
             # batch-1 prefill result reshapes into whole pages
             cache_len = -(-cache_len // page_size) * page_size
         self.cache_len = cache_len
+        # the decode kernel's KV tile: a page, or the dense entry point's
+        # own tile over the cache length (the kv_tiles counters)
+        self._kv_block = (page_size if cache_layout == "paged"
+                          else decode_block_s(cache_len))
+        self._kv_row_tiles = -(-cache_len // self._kv_block)
         # per-request sampling keys: each admission folds its rid into
         # the seed key, so a request's stream is independent of arrival
         # order and slot placement; the carried halves live per slot
@@ -942,6 +948,10 @@ class SlotScheduler:
                     self._hist = np.array(hist)
                     self._slot_keys = np.array(keys_d)
                 with tel.span("decode.collect"):
+                    if self._emit_w == 1:
+                        # rs.pos still holds the block's start positions
+                        block.attrs.update(self._kv_tiles(rs.pos, ran,
+                                                          emitted, bad))
                     block.attrs["kept"] = self._collect(
                         rs, ran, toks, emitted, pos_new, active_new, bad,
                         fetch.t1, retire)
@@ -968,6 +978,23 @@ class SlotScheduler:
                 for slot in range(B):
                     self._prefix.release(slot)
         return rs.done
+
+    def _kv_tiles(self, pos0, ran, emitted, bad) -> dict:
+        """The decode kernel's KV tiles over one block (``kv_tiles``) and
+        those holding a live key (``kv_tiles_live``), which it reads; it
+        skips the rest (kernels/decode_attention.py).  A slot runs live
+        for each step it emits, plus the step a non-finite logit froze
+        it in, and at step j attends its first ``pos0 + j + 1`` keys.
+        Greedy blocks only: a speculative verify runs the prefill
+        kernel."""
+        j = np.arange(self.block_steps)
+        steps = emitted.sum(axis=1) + bad
+        live = (j < steps[:, None]) & ran[:, None]
+        keys = pos0[:, None].astype(np.int64) + 1 + j
+        return {"kv_tiles": (self.max_slots * self._kv_row_tiles
+                             * self.block_steps),
+                "kv_tiles_live": int((-(-keys // self._kv_block)
+                                      * live).sum())}
 
     def _collect(self, rs: _RunState, ran, toks, emitted, pos_new,
                  active_new, bad, t_fetch: float, retire) -> int:

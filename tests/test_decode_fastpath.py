@@ -14,6 +14,8 @@ import pytest
 from repro.configs import get_config
 from repro.core import api as A
 from repro.core import quant as Q
+from repro.core.packing import pack_int4
+from repro.kernels import decode_attention as DA
 from repro.kernels import ops, ref as kref
 from repro.launch import steps as ST
 from repro.models import build_model
@@ -162,6 +164,107 @@ class TestDecodeAttentionKernel:
         want = kref.decode_attention_ref(q, k, v, ones, ones, 17)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-3, atol=1e-3)
+
+    # one row at each edge of the tile skip: empty, one key, a whole
+    # tile, one key into the next, the whole cache (S = 3 tiles of 16)
+    RAGGED = [0, 1, 16, 17, 48]
+
+    @staticmethod
+    def _ragged_case(layout, seed=5):
+        """(kernel output, oracle) over rows at ``RAGGED`` positions
+        through one entry point: the dense cache, a shuffled page pool,
+        or packed int4 tiles."""
+        rng = np.random.default_rng(seed)
+        b, s, kv, g, d, bs = len(TestDecodeAttentionKernel.RAGGED), 48, 2, \
+            3, 16, 16
+        q = jnp.asarray(rng.normal(size=(b, kv, g, d)), jnp.float32)
+        ks = jnp.asarray(np.abs(rng.normal(size=(kv,))) * 0.02 + 0.01,
+                         jnp.float32)
+        vs = jnp.asarray(np.abs(rng.normal(size=(kv,))) * 0.02 + 0.01,
+                         jnp.float32)
+        pos = jnp.asarray(TestDecodeAttentionKernel.RAGGED, jnp.int32)
+        bits = 4 if layout == "int4" else 8
+        lim = 7 if bits == 4 else 127
+        k, v = (jnp.asarray(rng.integers(-lim, lim + 1, size=(b, s, kv, d)),
+                            jnp.int8) for _ in range(2))
+        want = kref.decode_attention_ref(q, k, v, ks, vs, pos)
+        if layout == "int4":
+            got = ops.decode_attention(q, pack_int4(k), pack_int4(v), ks,
+                                       vs, pos, block_s=bs, kv_bits=4)
+        elif layout == "dense":
+            got = ops.decode_attention(q, k, v, ks, vs, pos, block_s=bs)
+        else:
+            # the same logical blocks at shuffled pages of one pool
+            n = s // bs
+            perm = rng.permutation(b * n)
+            pool = lambda c: jnp.zeros((b * n, bs, kv, d), c.dtype).at[
+                perm].set(c.reshape(b * n, bs, kv, d))
+            tab = jnp.asarray(perm.reshape(b, n), jnp.int32)
+            got = DA.decode_attention_tiles(q, pool(k), pool(v), tab, ks, vs,
+                                            pos, interpret=True)
+        return np.asarray(got), np.asarray(want)
+
+    @pytest.mark.parametrize("layout", ["dense", "paged", "int4"])
+    def test_ragged_positions_match_oracle(self, layout):
+        """Rows at 0, 1, block_s, block_s + 1 and S keys in one batch: the
+        tile skip reads exactly each row's live tiles, on every entry
+        point; the empty row is exact zeros."""
+        got, want = self._ragged_case(layout)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(got[0], 0.0)
+
+    def test_dead_tiles_repeat_the_last_live_page(self):
+        """The K/V index map's clamp: past a row's last live tile the
+        page repeats, so the pipeline elides the copy; an empty row
+        stays on its first page."""
+        tab = 100 + np.arange(15, dtype=np.int32).reshape(5, 3)
+        pos = np.asarray(self.RAGGED, np.int32)
+        pages = [[int(DA._kv_index(bi, si, tab, pos, block_s=16, n_s=3)[0])
+                  for si in range(3)] for bi in range(5)]
+        assert pages == [[100, 100, 100], [103, 103, 103],
+                         [106, 106, 106], [109, 110, 110],
+                         [112, 113, 114]]
+
+    @pytest.mark.parametrize("layout", ["dense", "paged"])
+    def test_dead_tiles_are_never_read(self, layout):
+        """Whole tiles past each row's last live tile hold NaN (a paged
+        row's table past it points at a NaN page): the output stays
+        finite and equals the oracle on a clean cache, so no dead tile
+        reaches the softmax."""
+        rng = np.random.default_rng(6)
+        b, s, kv, g, d, bs = len(self.RAGGED), 48, 2, 3, 16, 16
+        n = s // bs
+        q = jnp.asarray(rng.normal(size=(b, kv, g, d)), jnp.float32)
+        k, v = (rng.normal(size=(b, s, kv, d)).astype(np.float32)
+                for _ in range(2))
+        ones = jnp.ones((kv,), jnp.float32)
+        pos = np.asarray(self.RAGGED, np.int32)
+        want = kref.decode_attention_ref(q, jnp.asarray(k), jnp.asarray(v),
+                                         ones, ones, jnp.asarray(pos))
+        live = -(-pos // bs)                  # live tiles a row
+        if layout == "dense":
+            for r, t in enumerate(live):
+                k[r, t * bs:] = np.nan
+                v[r, t * bs:] = np.nan
+            got = ops.decode_attention(q, jnp.asarray(k), jnp.asarray(v),
+                                       ones, ones, jnp.asarray(pos),
+                                       block_s=bs)
+        else:
+            # pages 0..b*n-1 hold the rows' blocks, page b*n is all NaN
+            tab = np.arange(b * n, dtype=np.int32).reshape(b, n)
+            for r, t in enumerate(live):
+                tab[r, t:] = b * n
+            nan = np.full((1, bs, kv, d), np.nan, np.float32)
+            pool = lambda c: jnp.asarray(np.concatenate(
+                [c.reshape(b * n, bs, kv, d), nan]))
+            got = DA.decode_attention_tiles(q, pool(k), pool(v),
+                                            jnp.asarray(tab), ones, ones,
+                                            jnp.asarray(pos),
+                                            interpret=True)
+        got = np.asarray(got)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5,
+                                   atol=1e-5)
 
     def test_int8_mode_pallas_matches_xla(self):
         """_int8_matmul's use_pallas branch (raw x + act_scale into the

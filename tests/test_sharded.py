@@ -318,6 +318,24 @@ class TestPartialSoftmax:
         got = (acc / np.maximum(l, 1e-30)[..., None]).reshape(full.shape)
         np.testing.assert_allclose(got, full, atol=1e-5)
 
+    def test_partials_at_ragged_positions(self):
+        """Rows at 0, 1, a whole tile, one key past it and the whole
+        cache: the empty row's state is exactly (0, NEG_INF, 0), the
+        merge identity, and every row normalizes to the one-chip kernel
+        (``acc / max(l, eps) == decode_attention_tiles(...)``)."""
+        from repro.kernels import decode_attention as DA
+
+        q, k, v, ks, vs, _ = self._setup(b=5, s=48)
+        cur = jnp.asarray([0, 1, 16, 17, 48], jnp.int32)
+        full = np.asarray(DA.decode_attention_int8(
+            q, k, v, ks, vs, cur, block_s=16, interpret=True))
+        acc, m, l = (np.asarray(x) for x in DA.decode_attention_partials(
+            q, k, v, ks, vs, cur, block_s=16, interpret=True))
+        assert np.all(acc[0] == 0.0) and np.all(l[0] == 0.0)
+        assert np.all(m[0] == DA.NEG_INF)
+        got = acc / np.maximum(l, 1e-30)[..., None]
+        np.testing.assert_allclose(got, full, atol=1e-5)
+
     def test_empty_shard_is_merge_identity(self):
         """A shard with zero valid rows emits (m=-inf-ish, l=0, acc=0):
         merging it in changes nothing."""

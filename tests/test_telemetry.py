@@ -173,6 +173,27 @@ def test_span_attrs(served):
                and a.attrs["prefix_hit"] is False for a in admits)
 
 
+def test_decode_blocks_count_live_kv_tiles(parts):
+    """``kv_tiles``/``kv_tiles_live`` on ``sched.decode`` against a hand
+    count: one request in two slots, the other slot empty, over a
+    192-key cache that the dense kernel tiles in two tiles of 96."""
+    cfg = parts[0]
+    sched = _scheduler(parts, prompt_cap=128, gen_cap=64, prefill_chunk=32,
+                       block_steps=8)
+    assert sched.cache_len == 192
+    prompt = np.random.default_rng(0).integers(0, cfg.vocab, 90)
+    sched.run([Request(rid=0, tokens=prompt.astype(np.int32), max_gen=20)])
+    recs = tel.tracer().records(tel.tracer().run_ids()[-1])
+    blocks = [r for r in recs if r.name == "sched.decode"]
+    # the admission makes token 1 and leaves the slot at position 90;
+    # tokens 2-20 take three blocks of 8 steps, and the kernel runs all
+    # 24 over 91-114 keys: 91-96 in one tile, 97 on in two
+    assert [b.attrs["kv_tiles_live"] for b in blocks] == [
+        6 * 1 + 2 * 2, 8 * 2, 8 * 2]
+    # two slots x two tiles x eight steps, the empty slot's included
+    assert [b.attrs["kv_tiles"] for b in blocks] == [2 * 2 * 8] * 3
+
+
 def test_nothing_runnable_is_a_wait(parts):
     sched = _scheduler(parts)
     sched.run([Request(rid=0, tokens=parts[-1][0, :6], max_gen=2,
