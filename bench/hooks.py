@@ -1,10 +1,12 @@
 """Every place where the benchmark touches the program's own names.
 
-The rest of ``bench/`` knows the program only through this file: how to
-turn the benchmark's weights into the program's parameter tree, how to
+The rest of ``bench/`` knows the program only through this file and the
+architecture modules (``bench/arch/<arch>.py``: the program's
+``ModelConfig`` and parameter tree for a configuration).  Here: how to
 assemble the serving engine the way ``Engine.from_checkpoint`` does, and
 how to watch ``SlotScheduler`` from outside.  A rename in the program
-breaks this file and its test (``tests/bench/test_bench_hooks.py``), nothing
+breaks this file or an architecture module, and their tests
+(``tests/bench/test_bench_hooks.py``, ``test_bench_arch.py``), nothing
 else.
 
 What is watched: ``SlotScheduler._admit`` (one admission: the chunked
@@ -25,6 +27,8 @@ import time
 import traceback
 from pathlib import Path
 
+from bench import arch
+
 CHECKOUT = Path(__file__).resolve().parents[1]
 SRC = CHECKOUT / "src"
 
@@ -43,23 +47,6 @@ def enable_compile_cache() -> str:
     import_program()
     from repro.launch import compile_cache
     return compile_cache.enable()
-
-
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a benchmark configuration file
-    (Hugging Face key names)."""
-    import_program()
-    from repro.configs.base import ModelConfig
-
-    if not cfg["tie_word_embeddings"]:
-        raise ValueError("the program's llama-arch stack has tied "
-                         "embeddings only")
-    return ModelConfig(
-        name=cfg["name"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        tie_embeddings=True, rope_base=float(cfg["rope_theta"]))
 
 
 def set_norm_eps(model, eps: float) -> int:
@@ -86,22 +73,6 @@ def set_norm_eps(model, eps: float) -> int:
     return n
 
 
-def program_params(weights: dict) -> dict:
-    """The benchmark's weight layout (``bench/weights.py``) as the
-    program's parameter tree."""
-    stack = {}
-    for i, lw in enumerate(weights["layers"]):
-        stack[f"layer{i}"] = {
-            "pre_norm": {"scale": lw["attn_norm"]},
-            "attn": {n: {"w": lw[n]} for n in ("wq", "wk", "wv", "wo")},
-            "ffn_norm": {"scale": lw["mlp_norm"]},
-            "ffn": {"gate": {"w": lw["w_gate"]}, "up": {"w": lw["w_up"]},
-                    "down": {"w": lw["w_down"]}},
-        }
-    stack["final_norm"] = {"scale": weights["final_norm"]}
-    return {"embed": {"table": weights["embed"]}, "stack": stack}
-
-
 def build_engine(cfg: dict, weights: dict):
     """The serving engine on the given weights, assembled as
     ``Engine.from_checkpoint`` assembles it: the §2 calibration pass on
@@ -117,18 +88,20 @@ def build_engine(cfg: dict, weights: dict):
     from repro.launch.engine import Engine, prepare_int8
     from repro.models import build_model
 
-    pcfg = program_config(cfg)
+    mod = arch.of(cfg)
+    pcfg = mod.program_config(cfg)
     model = build_model(pcfg)
-    if set_norm_eps(model, cfg["rms_norm_eps"]) < 2 * pcfg.n_layers + 1:
+    if set_norm_eps(model, cfg["rms_norm_eps"]) < mod.norm_count(cfg):
         raise ValueError("the program's norms have moved; update "
                          "bench/hooks.py:set_norm_eps")
-    params = program_params(weights)
+    params = mod.program_params(weights)
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if (jax.tree.structure(want) != jax.tree.structure(params) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
             zip(jax.tree.leaves(want), jax.tree.leaves(params)))):
         raise ValueError("the program's parameter tree has changed; "
-                         "update bench/hooks.py:program_params")
+                         f"update bench/arch/{cfg['arch']}.py:"
+                         "program_params")
     policy = A.QuantPolicy(bits=cfg["weight_bits"], kv_int8=True,
                            kv_bits=cfg["kv_bits"],
                            use_pallas=jax.default_backend() == "tpu")

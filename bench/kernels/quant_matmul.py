@@ -2,24 +2,19 @@
 quantized in the kernel and multiplied on the MXU as int8 by int8 weights
 (K, N) with per-channel f32 scales; bf16 out.  Its rate is the int8 peak.
 
-The calls are the model's seven projections per layer, at the shape the
-kernel is handed: M = the prefill chunk in an admission (every chunk of
-the padded prompt runs), M = the slot count in a decode step.
+The calls are a layer's projections as the configuration's architecture
+module lists them (``bench/arch/<arch>.py:projections``), at the shape
+the kernel is handed: M = the prefill chunk in an admission (every chunk
+of the padded prompt runs), M = the slot count in a decode step.
 """
+from bench import arch
+
 OPS_PEAK = "int8_ops"
 
 
 def cost(m: int, k: int, n: int) -> tuple:
     """(operations, bytes) of one call."""
     return 2 * m * k * n, 2 * m * k + k * n + 4 * n + 4 + 2 * m * n
-
-
-def projections(cfg: dict) -> list:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    return [(d, h * hd), (d, kv * hd), (d, kv * hd), (h * hd, d),
-            (d, f), (d, f), (f, d)]
 
 
 def calls(run, kind: str, span):
@@ -31,6 +26,6 @@ def calls(run, kind: str, span):
     else:
         m, times = srv["max_slots"], srv["block_steps"]
     times *= cfg["num_hidden_layers"]
-    for k, n in projections(cfg):
+    for k, n in arch.of(cfg).projections(cfg):
         ops, nbytes = cost(m, k, n)
         yield ops * times, nbytes * times

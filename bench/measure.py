@@ -13,7 +13,7 @@ import importlib.util
 import math
 from pathlib import Path
 
-from bench import hooks
+from bench import arch, hooks
 
 KERNELS = Path(__file__).resolve().parent / "kernels"
 
@@ -108,19 +108,6 @@ def roofline_pct(run: Run, kernel: str, kind: str) -> float | None:
     return 100.0 * least / busy
 
 
-def model_ops(cfg: dict) -> dict:
-    """Operations of one token through the model, by part: the matrix
-    products (2 per weight), and per key attended the score and value
-    products (4 * heads * head_dim per layer)."""
-    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    per_layer = d * (h + 2 * kv) * hd + h * hd * d + 3 * d * f
-    return {"matmul": 2 * per_layer * cfg["num_hidden_layers"],
-            "readout": 2 * d * v,
-            "per_key": 4 * h * hd * cfg["num_hidden_layers"]}
-
-
 def decode_mfu_pct(run: Run) -> float | None:
     """Useful model operations of the decode blocks over their host time
     at the int8 peak: each token a slot kept (budgets cap what a block
@@ -128,7 +115,7 @@ def decode_mfu_pct(run: Run) -> float | None:
     spans = run.spans("decode", traced=False)
     if not spans:
         return None
-    mo = model_ops(run.cfg)
+    mo = arch.of(run.cfg).model_ops(run.cfg)
     ops = 0
     for b in spans:
         for rid, had, budget, pos in b.slots:
@@ -146,7 +133,7 @@ def prefill_mfu_pct(run: Run) -> float | None:
     spans = run.spans("admit", traced=False)
     if not spans:
         return None
-    mo = model_ops(run.cfg)
+    mo = arch.of(run.cfg).model_ops(run.cfg)
     ops = sum(a.prompt_len * mo["matmul"] + mo["readout"]
               + a.prompt_len * (a.prompt_len + 1) // 2 * mo["per_key"]
               for a in spans)

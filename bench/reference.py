@@ -1,16 +1,16 @@
-"""Plain float32 forward pass of a llama-arch decoder.
+"""Plain float32 forward pass of a decoder.
 
 Independent of the program: it reads the configuration file's Hugging
 Face keys and makes its weights from the seed through ``bench/weights.py``
-(the same bits the program was given), one layer at a time, so that
-granite-8b's layers never have to sit in float32 all at once.
+and the configuration's architecture module (the same bits the program
+was given), one layer at a time, so that granite-8b's layers never have
+to sit in float32 all at once.
 
-Architecture, as the configuration files state it: token embedding; per
-layer RMSNorm -> GQA attention with rotary positions (rotate-half, base
-``rope_theta``; query head h reads key/value head h // (H / KV)) -> add ->
-RMSNorm -> SwiGLU MLP -> add; final RMSNorm; logits = h @ embed^T.
-Norm scales are 1, as ``bench/weights.py`` makes them.
-Every matrix product runs at ``highest`` precision.
+Shared by every architecture: token embedding; each layer as the module's
+``reference_layer`` computes it; final RMSNorm (scale 1, as
+``bench/weights.py`` makes it); logits = h @ embed^T.  ``_rms`` and
+``_rope`` are the helpers the architecture modules import.  Every matrix
+product runs at ``highest`` precision.
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch
 from bench import weights as W
 
 
@@ -35,27 +36,6 @@ def _rope(x, pos, base):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def _layer(cfg: dict, lw: dict, x):
-    """One decoder layer over one sequence x (S, d)."""
-    h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    eps, s = cfg["rms_norm_eps"], x.shape[0]
-    pos = jnp.arange(s)
-    a = _rms(x, lw["attn_norm"], eps)
-    q = _rope((a @ lw["wq"]).reshape(s, h, hd), pos, cfg["rope_theta"])
-    k = _rope((a @ lw["wk"]).reshape(s, kv, hd), pos, cfg["rope_theta"])
-    v = (a @ lw["wv"]).reshape(s, kv, hd)
-    k = jnp.repeat(k, h // kv, axis=1)
-    v = jnp.repeat(v, h // kv, axis=1)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
-    scores = jnp.where(pos[:, None] >= pos[None, :], scores, -jnp.inf)
-    o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, -1), v)
-    x = x + o.reshape(s, h * hd) @ lw["wo"]
-    m = _rms(x, lw["mlp_norm"], eps)
-    g = m @ lw["w_gate"]
-    return x + (g * jax.nn.sigmoid(g) * (m @ lw["w_up"])) @ lw["w_down"]
-
-
 def gaps(cfg: dict, seed: int, seqs: list, starts: list, *, rows: int,
          length: int, dtype=jnp.bfloat16) -> list:
     """For each sequence (prompt + served tokens) and the index where its
@@ -68,6 +48,7 @@ def gaps(cfg: dict, seed: int, seqs: list, starts: list, *, rows: int,
     program; the reference computes with exactly those values, in
     float32."""
     key = W.root_key(seed)
+    mod = arch.of(cfg)
     pad = -(-length // 128) * 128
     if len(seqs) > rows or max(len(t) for t in seqs) > pad:
         raise ValueError("sample larger than the padded reference batch")
@@ -78,11 +59,11 @@ def gaps(cfg: dict, seed: int, seqs: list, starts: list, *, rows: int,
     @jax.jit
     def make(key, i):
         return jax.tree.map(lambda a: a.astype(jnp.float32),
-                            W.layer_params(cfg, key, i, dtype))
+                            mod.layer_params(cfg, key, i, dtype))
 
     @jax.jit
     def layer(lw, x):
-        return jax.lax.map(lambda xi: _layer(cfg, lw, xi), x)
+        return jax.lax.map(lambda xi: mod.reference_layer(cfg, lw, xi), x)
 
     @jax.jit
     def gap_row(emb, h, nxt):

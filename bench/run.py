@@ -51,7 +51,7 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import check, hooks, measure, traffic  # noqa: E402
+from bench import arch, check, hooks, measure, traffic  # noqa: E402
 from bench import weights as W  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_trace"
@@ -74,6 +74,7 @@ def load_cell(name: str) -> tuple:
     cell = cells[name]
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     cfg = json.loads((ROOT / conf["file"]).read_text())
+    arch.of(cfg)        # a configuration names its architecture module
     return cell, cfg, traffic.load(cell["traffic"]), spec
 
 

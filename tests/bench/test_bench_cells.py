@@ -22,6 +22,7 @@ def test_every_name_resolves_to_a_file():
         assert path.is_file() and path.parts[len(ROOT.parts)] in ("bench",)
         cfg = json.loads(path.read_text())
         assert cfg["name"] == c["name"]
+        assert (ROOT / "bench" / "arch" / f"{cfg['arch']}.py").is_file()
         assert cfg["limits"]["logit_gap"] is not None
     for w in SPEC["workloads"]:
         assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").is_file()

@@ -7,7 +7,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import hooks, weights
+from bench import arch, hooks, weights
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -23,12 +23,13 @@ def engine(cfg):
 
 
 def test_program_config_matches_file(cfg):
-    pcfg = hooks.program_config(cfg)
+    llama = arch.load("llama")
+    pcfg = llama.program_config(cfg)
     assert (pcfg.n_layers, pcfg.d_model, pcfg.n_heads, pcfg.n_kv_heads,
             pcfg.head_dim, pcfg.d_ff, pcfg.vocab) == (2, 64, 4, 2, 16, 128,
                                                      256)
     with pytest.raises(ValueError):
-        hooks.program_config(dict(cfg, tie_word_embeddings=False))
+        llama.program_config(dict(cfg, tie_word_embeddings=False))
 
 
 def test_norms_take_the_files_eps(cfg):
@@ -37,8 +38,10 @@ def test_norms_take_the_files_eps(cfg):
     from repro.models import build_model
     from repro.models.layers import RMSNorm
 
-    model = build_model(hooks.program_config(cfg))
-    assert hooks.set_norm_eps(model, 1e-5) == 2 * cfg["num_hidden_layers"] + 1
+    llama = arch.load("llama")
+    model = build_model(llama.program_config(cfg))
+    assert hooks.set_norm_eps(model, 1e-5) == llama.norm_count(cfg)
+    assert llama.norm_count(cfg) == 2 * cfg["num_hidden_layers"] + 1
     norms = [model.stack.final_norm] + [
         n for b in model.stack.blocks for n in (b.pre_norm, b.ffn_norm)]
     assert all(isinstance(n, RMSNorm) and n.eps == 1e-5 for n in norms)

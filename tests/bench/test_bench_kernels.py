@@ -6,7 +6,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench import measure
+from bench import arch, measure
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -32,7 +32,8 @@ def test_quant_matmul_hand_counts():
     # the seven projections hold every matrix weight of a layer
     for name, per_layer in (("smollm-135m", 3538944),
                             ("granite-8b", 218103808)):
-        assert sum(k * n for k, n in qm.projections(cfg(name))) == per_layer
+        calls = arch.load("llama").projections(cfg(name))
+        assert sum(k * n for k, n in calls) == per_layer
 
 
 def test_quant_matmul_calls_per_span():
@@ -70,7 +71,7 @@ def test_attention_hand_counts():
 @pytest.mark.parametrize("name", ["smollm-135m", "granite-8b"])
 def test_model_ops(name):
     c = cfg(name)
-    mo = measure.model_ops(c)
+    mo = arch.of(c).model_ops(c)
     per_layer = {"smollm-135m": 3538944, "granite-8b": 218103808}[name]
     assert mo["matmul"] == 2 * per_layer * c["num_hidden_layers"]
     assert mo["readout"] == 2 * c["hidden_size"] * c["vocab_size"]
