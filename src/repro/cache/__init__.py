@@ -8,9 +8,9 @@
     window-sized ring buffer, everything else a dense cache.
   * ``"dense"`` — force dense everywhere (the slot scheduler's
     requirement: absolute slots).
-  * ``"paged"`` — page-pool + block-table for non-windowed layers
-    (windowed layers keep their ring: a window-bounded buffer is already
-    the right layout for SWA).
+  * ``"paged"`` — page-pool + block-table for every layer; a windowed
+    layer's pages hold every position too, and its window is a mask (the
+    slot scheduler's requirement, as for dense).
 
 ``bits`` picks the quantized storage width (8 = int8, 4 = packed int4
 nibbles along the head dim — half the cache bytes); it is static aux
@@ -40,7 +40,7 @@ def make_cache(batch, max_len, n_kv, head_dim, *, dtype, quantized=False,
     if bits not in KV_BITS:
         raise ValueError(f"unknown kv cache bits {bits!r} (use one of "
                          f"{KV_BITS})")
-    if window is not None and layout != "dense" and window < max_len:
+    if window is not None and layout == "ring" and window < max_len:
         return RingCache.init(batch, window, n_kv, head_dim, dtype=dtype,
                               quantized=quantized, bits=bits)
     if layout == "paged":

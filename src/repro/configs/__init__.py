@@ -17,6 +17,7 @@ _MODULES = {
     "mamba2-780m": "mamba2_780m",
     "llava-next-34b": "llava_next_34b",
     "hymba-1.5b": "hymba_1_5b",
+    "mellum2-12b": "mellum2_12b",
 }
 
 ARCHS = list(_MODULES)

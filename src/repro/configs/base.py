@@ -1,9 +1,10 @@
 """ModelConfig — single dataclass describing every assigned architecture.
 
-Layer-pattern helpers (``layer_kind``/``attn_window``/``ffn_kind``) express
-gemma3's 5:1 local:global attention, hymba's hybrid layers with three
-global-attention layers, mixtral's all-layer SWA, and mamba2's attention-free
-stack — all through one Stack implementation.
+Layer-pattern helpers (``layer_kind``/``attn_window``/``ffn_kind``/
+``rope_yarn``) express gemma3's 5:1 local:global attention, mellum2's 3:1
+with YaRN on its global layers, hymba's hybrid layers with three
+global-attention layers, mixtral's all-layer SWA, and mamba2's
+attention-free stack — all through one Stack implementation.
 """
 from __future__ import annotations
 
@@ -11,6 +12,20 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (transformers' ``rope_type: "yarn"``, with
+    ``truncate`` true): frequencies between the ``beta_fast`` and
+    ``beta_slow`` rotation counts over ``original_max`` positions ramp
+    from extrapolated to interpolated by ``factor``, and cos/sin scale by
+    ``attention_factor``."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,10 +54,13 @@ class ModelConfig:
     window_all: bool = False                    # SWA on every layer (mixtral)
     local_global_ratio: Optional[Tuple[int, int]] = None  # gemma3 (5, 1)
     global_attn_layers: Tuple[int, ...] = ()    # hymba full-attn layers
+    global_yarn: Optional[Yarn] = None          # mellum2: YaRN on global layers
 
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
+    # the training forward's slots per expert per sequence, over the mean;
+    # serving and calibration route dropless
     capacity_factor: float = 1.25
 
     # --- SSM (mamba2 / hybrid) ---
@@ -104,6 +122,13 @@ class ModelConfig:
 
     def ffn_kind(self, i: int) -> str:
         return self.ffn
+
+    def rope_yarn(self, i: int) -> Optional[Yarn]:
+        """Layer i's YaRN scaling: ``global_yarn`` on the full-attention
+        layers of a windowed stack, else None (plain rotary)."""
+        if self.global_yarn is None or self.attn_window(i) is not None:
+            return None
+        return self.global_yarn
 
     # ---- sizing helpers (roofline MODEL_FLOPS) ----
     def param_count(self) -> int:

@@ -145,18 +145,23 @@ def init_qparams(model, params: dict, policy: QuantPolicy) -> dict:
         # get the same leading axis so lax.scan slices them per layer
         base_ndim = 3 if isinstance(layer, ExpertDense) else 2
         lead = w.shape[: w.ndim - base_ndim]
+        # |w| and its max are exact in the weights' own dtype: no float32
+        # copy of the weights
         if wspec.per_channel:
             # per output channel: (out,) / (E, out), + leading stack dims
-            t_w = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2)
+            t_w = jnp.max(jnp.abs(w), axis=-2).astype(jnp.float32)
         else:
             axes = tuple(range(w.ndim - base_ndim, w.ndim))
-            t_w = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=axes)
+            t_w = jnp.max(jnp.abs(w), axis=axes).astype(jnp.float32)
+        # expert layers observe one activation threshold per expert
+        act_lead = lead + ((layer.num_experts,)
+                           if isinstance(layer, ExpertDense) else ())
         entry = {
             "w": {
                 "t_max": t_w,
                 "alpha": jnp.ones_like(t_w),
             },
-            "act": calib.init_observer(policy.act_spec(), lead_shape=lead),
+            "act": calib.init_observer(policy.act_spec(), lead_shape=act_lead),
         }
         if policy.pointwise_scales:
             entry["w"]["pointwise"] = jnp.ones(w.shape, jnp.float32)
@@ -399,39 +404,95 @@ def dense_forward(layer, params: dict, x: jax.Array, ctx: Optional[QuantCtx]):
     return y
 
 
-def expert_dense_forward(layer, params: dict, x: jax.Array, ctx: Optional[QuantCtx]):
-    """x: (..., E, C, in) @ w: (E, in, out) -> (..., E, C, out)."""
+def _expert_capacity_forward(layer, params: dict, x: jax.Array,
+                             ctx: Optional[QuantCtx]):
+    """The training forward's capacity layout: x (..., E, C, in) @ w
+    (E, in, out) -> (..., E, C, out), each expert's activations at its
+    own threshold.  Calibration routes dropless, never here."""
+    ein = "...ecd,edf->...ecf"
     if ctx is None or not ctx.enabled(layer):
-        return jnp.einsum("...ecd,edf->...ecf", x, params["w"])
+        return jnp.einsum(ein, x, params["w"])
+    spec = ctx.policy.act_spec()
+    astate = ctx.qparams[layer.path]["act"]
+    if ctx.mode == "fake":
+        # one activation threshold per expert: the layer's own fake
+        # quantizer mapped over the E axis
+        e_axis = x.ndim - 3
+        xq = jax.vmap(lambda xe, st: _fq_act(xe, st, spec),
+                      in_axes=(e_axis, 0), out_axes=e_axis)(x, astate)
+        wq = _fq_weight(params["w"], ctx.qparams[layer.path]["w"],
+                        ctx.policy.weight_spec())
+        return jnp.einsum(ein, xq.astype(x.dtype), wq)
+    # one activation threshold per expert, broadcast along the E axis
+    astate = {k: v[:, None, None] for k, v in astate.items()}
+    if ctx.mode == "int8":
+        t_adj = jnp.maximum(
+            Q.adjusted_threshold(astate["t_max"], astate["alpha"], spec), 1e-8)
+        s_x = (spec.levels / t_adj).astype(jnp.float32)     # (E, 1, 1)
+        x_int = jnp.clip(jnp.round(x * s_x), spec.qmin,
+                         spec.qmax).astype(jnp.int8)
+        # (..., E, C, in) @ (E, in, out) with int32 accumulation
+        acc = jnp.einsum(ein, x_int, params["w_q"],
+                         preferred_element_type=jnp.int32)
+        scale = params["w_scale"][:, None, :] / s_x           # (E, 1, out)
+        return (acc.astype(jnp.float32) * scale).astype(x.dtype)
+    raise ValueError(f"expert layers calibrate on the dropless layout, "
+                     f"not the capacity layout (mode {ctx.mode!r})")
+
+
+def expert_dense_forward(layer, params: dict, x: jax.Array,
+                         ctx: Optional[QuantCtx], groups):
+    """x: (M, in) rows grouped by expert (``groups``: contiguous groups of
+    ``groups.sizes`` rows, in expert order) @ w: (E, in, out) -> (M, out).
+
+    Activation thresholds are per expert, shape (E,): calibration folds
+    only each expert's routed rows into its observer (a tile's padding
+    rows never), and every row quantizes with its expert's threshold.
+    The int8 path runs the ``expert_matmul`` kernel under
+    ``policy.use_pallas``, else the same grouped contraction in jnp
+    (``lax.ragged_dot``, int32 accumulation), which is the kernel's
+    oracle.  Without ``groups``: ``_expert_capacity_forward``."""
+    if groups is None:
+        return _expert_capacity_forward(layer, params, x, ctx)
+    sizes = groups.sizes
+    if ctx is None or not ctx.enabled(layer):
+        return jax.lax.ragged_dot(x, params["w"], sizes)
+    spec = ctx.policy.act_spec()
     if ctx.mode == "calibrate":
-        ctx.updates[layer.path] = calib.update_observer(
-            ctx.qparams[layer.path]["act"],
-            x,
-            ctx.policy.act_spec(),
-            kind=ctx.policy.observer,
-            percentile=ctx.policy.percentile,
-        )
-        return jnp.einsum("...ecd,edf->...ecf", x, params["w"])
+        ctx.updates[layer.path] = calib.update_expert_observer(
+            ctx.qparams[layer.path]["act"], x, groups.row_expert,
+            groups.row_valid, layer.num_experts, kind=ctx.policy.observer)
+        return jax.lax.ragged_dot(x, params["w"], sizes)
+    astate = ctx.qparams[layer.path]["act"]
     if ctx.mode == "fake":
         qs = ctx.qparams[layer.path]
-        xq = _fq_act(x, qs["act"], ctx.policy.act_spec()).astype(x.dtype)
+        rows = {k: v[groups.row_expert][:, None] for k, v in astate.items()}
+        if spec.symmetric:
+            xq = Q.fake_quant_symmetric(x, rows["t_max"], rows["alpha"], spec)
+        else:
+            xq = Q.fake_quant_asymmetric(x, rows["t_l"], rows["t_r"],
+                                         rows["alpha_t"], rows["alpha_r"],
+                                         spec)
         wq = _fq_weight(params["w"], qs["w"], ctx.policy.weight_spec())
-        return jnp.einsum("...ecd,edf->...ecf", xq, wq)
+        return jax.lax.ragged_dot(xq.astype(x.dtype), wq, sizes)
     if ctx.mode == "int8":
-        astate = ctx.qparams[layer.path]["act"]
-        spec = ctx.policy.act_spec()
         t_adj = jnp.maximum(
-            Q.adjusted_threshold(astate["t_max"], astate["alpha"], spec), 1e-8
-        )
-        s_x = spec.levels / t_adj
-        x_int = jnp.clip(jnp.round(x * s_x), spec.qmin, spec.qmax).astype(jnp.int8)
-        # (..., E, C, in) @ (E, in, out) with int32 accumulation
-        acc = jnp.einsum(
-            "...ecd,edf->...ecf", x_int, params["w_q"],
-            preferred_element_type=jnp.int32,
-        )
-        scale = (params["w_scale"] / s_x).astype(jnp.float32)  # (E, out)
-        return (acc.astype(jnp.float32) * scale[:, None, :]).astype(x.dtype)
+            Q.adjusted_threshold(astate["t_max"], astate["alpha"], spec), 1e-8)
+        s_x = (spec.levels / t_adj).astype(jnp.float32)          # (E,)
+        # the combined per-(expert, out-channel) dequant scale
+        scale = (params["w_scale"] / s_x[:, None]).astype(jnp.float32)
+        if ctx.policy.use_pallas:
+            from repro.kernels import ops as kops
+
+            return kops.expert_matmul(
+                x, params["w_q"], scale, s_x, groups.tile_expert,
+                groups.n_live, block_m=groups.block_m, bits=spec.bits,
+            ).astype(x.dtype)
+        from repro.kernels import ref as kref
+
+        return kref.expert_matmul_ref(
+            x, params["w_q"], scale, s_x, groups.row_expert, sizes,
+            bits=spec.bits).astype(x.dtype)
     raise ValueError(ctx.mode)
 
 
@@ -512,6 +573,37 @@ def _int8_matmul(x, w_q, w_scale, astate, aspec, *, use_pallas=False,
 # ---------------------------------------------------------------------------
 
 
+def _quantize_weight(w, wstate: dict, spec: Q.QuantSpec):
+    """(w_q int8, w_scale float32) of one weight at its thresholds."""
+    w_q, s = _quantize_weight_fused(w, wstate, spec)
+    # the small scale stays out of the fused program, where XLA would
+    # fold 1 / (levels / t) into t / levels, rounded differently
+    w_scale = 1.0 / s
+    if spec.per_channel:
+        w_scale = jnp.squeeze(w_scale, axis=-2)
+    return w_q, w_scale.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _quantize_weight_fused(w, wstate: dict, spec: Q.QuantSpec):
+    """(w_q, s): one fused program, so the float32 copies of ``w`` are
+    never materialized (eager, they held four times the weight's bytes at
+    once)."""
+    w = w.astype(jnp.float32)
+    if "pointwise" in wstate:
+        w = Q.apply_pointwise_scale(w, wstate["pointwise"])
+    t = wstate["t_max"]
+    alpha = wstate["alpha"]
+    if spec.per_channel:
+        shape = _weight_threshold_shape(w)
+        t = t.reshape(shape)
+        alpha = alpha.reshape(shape)
+    t_adj = jnp.maximum(Q.adjusted_threshold(t, alpha, spec), 1e-8)
+    s = spec.levels / t_adj
+    w_q = jnp.clip(jnp.round(w * s), spec.qmin, spec.qmax).astype(jnp.int8)
+    return w_q, s
+
+
 def convert_to_int8(model, params: dict, qparams: dict, policy: QuantPolicy) -> dict:
     """Replace every quantized Dense/ExpertDense 'w' with int8 + scales.
 
@@ -520,27 +612,12 @@ def convert_to_int8(model, params: dict, qparams: dict, policy: QuantPolicy) -> 
     paper targets), biases as int32 at the combined scale (eq. 20).
     """
     out = jax.tree.map(lambda x: x, params)  # structural copy
+    spec = policy.weight_spec()
     for layer, lp in _quant_layers_with_params(model, out, policy):
         if layer.path not in qparams:
             continue
-        wstate = qparams[layer.path]["w"]
-        w = lp.pop("w").astype(jnp.float32)
-        if "pointwise" in wstate:
-            w = Q.apply_pointwise_scale(w, wstate["pointwise"])
-        spec = policy.weight_spec()
-        t = wstate["t_max"]
-        alpha = wstate["alpha"]
-        if spec.per_channel:
-            shape = _weight_threshold_shape(w)
-            t = t.reshape(shape)
-            alpha = alpha.reshape(shape)
-        t_adj = jnp.maximum(Q.adjusted_threshold(t, alpha, spec), 1e-8)
-        s = spec.levels / t_adj
-        lp["w_q"] = jnp.clip(jnp.round(w * s), spec.qmin, spec.qmax).astype(jnp.int8)
-        w_scale = 1.0 / s
-        if spec.per_channel:
-            w_scale = jnp.squeeze(w_scale, axis=-2)
-        lp["w_scale"] = w_scale.astype(jnp.float32)
+        lp["w_q"], lp["w_scale"] = _quantize_weight(
+            lp.pop("w"), qparams[layer.path]["w"], spec)
         if "b" in lp:
             astate = qparams[layer.path]["act"]
             aspec = policy.act_spec(layer.act_unsigned)

@@ -80,14 +80,44 @@ def update_observer(
     }
 
 
+def update_expert_observer(state: dict, x: jax.Array, row_expert: jax.Array,
+                           row_valid: jax.Array, num_experts: int,
+                           kind: ObserverKind = "max_abs") -> dict:
+    """One calibration step of per-expert observers (state leaves (E,)):
+    rows (M, in) grouped by expert, ``row_expert`` (M,) each row's expert,
+    ``row_valid`` (M,) False on padding rows, which no observer sees."""
+    if kind == "percentile":
+        raise ValueError("per-expert observers keep running max/min only; "
+                         "use observer='max_abs' for expert layers")
+    xf = x.astype(jnp.float32)
+    e = row_expert
+    seen = jnp.zeros((num_experts,), bool).at[e].max(row_valid)
+    amax = jnp.where(row_valid, jnp.max(jnp.abs(xf), axis=-1), 0.0)
+    lo = jnp.where(row_valid, jnp.min(xf, axis=-1), jnp.inf)
+    hi = jnp.where(row_valid, jnp.max(xf, axis=-1), -jnp.inf)
+    z = jnp.zeros((num_experts,), jnp.float32)
+    return {
+        "t_max": jnp.maximum(state["t_max"], z.at[e].max(amax)),
+        "t_min": jnp.minimum(state["t_min"], (z + jnp.inf).at[e].min(lo)),
+        "t_hi": jnp.maximum(state["t_hi"], (z - jnp.inf).at[e].max(hi)),
+        "count": state["count"] + seen.astype(jnp.int32),
+    }
+
+
 def observer_thresholds(state: dict, spec: QuantSpec) -> dict:
     """Finalize calibration into threshold parameters (§3.1.3 init).
 
     Symmetric: T_max from the observer, trained scale alpha=1.
     Asymmetric: (T_l, T_r) from min/max, alpha_t=0, alpha_r=1 (§3.1.4).
+    An entry the calibration never saw (an expert no calibration token
+    was routed to) takes the largest threshold along its last axis.
     """
     shape = state["t_max"].shape
     ones = jnp.ones(shape, jnp.float32)
+    if len(shape):
+        seen = state["count"] > 0
+        state = dict(state, t_max=jnp.where(seen, state["t_max"], jnp.max(
+            jnp.where(seen, state["t_max"], 0.0), axis=-1, keepdims=True)))
     t_min = jnp.where(jnp.isfinite(state["t_min"]), state["t_min"], 0.0)
     t_hi = jnp.where(jnp.isfinite(state["t_hi"]), state["t_hi"], 0.0)
     return {

@@ -17,4 +17,5 @@ PALLAS_MODULES = (
     "prefill_attention",
     "quant_matmul",
     "fake_quant",
+    "expert_matmul",
 )

@@ -89,6 +89,18 @@ scheduler slot) skips every tile, ends with ``acc == 0, l == 0`` and
 normalizes to exact zeros in the epilogue; the partials kernel returns
 ``(0, NEG_INF, 0)`` for it, the merge identity.
 
+Sliding window
+--------------
+A static ``window`` (a windowed layer's width; None for a full layer,
+which compiles to the kernel without it) also bounds each row from the
+left: its query at position ``cur_pos[b] - 1`` sees keys from
+``cur_pos[b] - window`` on.  Tiles left of that clamp to the row's
+first live tile, ``max(cur_pos[b] - window, 0) // block_s``, so their
+copies are elided as the dead tail's are; ``pl.when`` skips their
+compute, and the edge tile masks the keys left of the window.  The
+cache still holds every position (dense or paged): the window is a mask
+over it.
+
 A bf16 cache runs through the same kernel with scales == 1.  The
 pure-jnp oracle is kernels/ref.py::decode_attention_ref.
 """
@@ -121,17 +133,22 @@ def decode_block_s(s: int, block_s: int = 128) -> int:
     return bs
 
 
-def _kv_index(bi, si, tab, pos, *, block_s: int, n_s: int):
+def _kv_index(bi, si, tab, pos, *, block_s: int, n_s: int, window=None):
     """K/V index map: row ``bi``'s page for tile ``si``, clamped to the
-    row's last live tile ``max(ceil(pos / block_s) - 1, 0)``.  A dead
-    tile repeats the previous grid step's page, so its copy is elided,
-    and the table is never read past a row's live blocks."""
+    row's last live tile ``max(ceil(pos / block_s) - 1, 0)`` and, with a
+    window, to its first, ``max(pos - window, 0) // block_s``.  A dead
+    tile repeats a neighbouring grid step's page, so its copy is elided,
+    and the table is never read outside a row's live blocks."""
     last = jnp.minimum(n_s - 1, (jnp.maximum(pos[bi], 1) - 1) // block_s)
-    return (tab[bi, jnp.minimum(si, last)], 0, 0, 0)
+    if window is None:
+        return (tab[bi, jnp.minimum(si, last)], 0, 0, 0)
+    first = jnp.maximum(pos[bi] - window, 0) // block_s
+    return (tab[bi, jnp.clip(si, first, last)], 0, 0, 0)
 
 
 def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
-                l_ref, *, bi, si, block_s: int, dim: int, kv_bits: int):
+                l_ref, *, bi, si, block_s: int, dim: int, kv_bits: int,
+                window=None):
     """One online-softmax tile update for every KV head of the tile
     (shared by the normalized kernel and the sequence-parallel partials
     kernel — same math up to, but not including, the epilogue)."""
@@ -142,9 +159,13 @@ def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # a tile with no live key is skipped: its index map repeated the
-    # last live tile's, so its contents are not this tile's
-    @pl.when(si * block_s < pos_ref[bi])
+    # a tile with no live key is skipped: its index map repeated a live
+    # tile's, so its contents are not this tile's
+    live = si * block_s < pos_ref[bi]
+    if window is not None:
+        live &= (si + 1) * block_s > pos_ref[bi] - window
+
+    @pl.when(live)
     def _tile():
         # mask the unwritten tail (cache slots >= this row's cur_pos),
         # per batch row so slot-ragged positions mask per slot.  k_pos
@@ -152,6 +173,8 @@ def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
         k_pos = si * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_s), 1)
         valid = k_pos < pos_ref[bi]
+        if window is not None:
+            valid &= k_pos >= pos_ref[bi] - window
         inv_sqrt_d = jax.lax.rsqrt(jnp.asarray(dim, jnp.float32))
 
         for h in range(q_ref.shape[1]):
@@ -192,14 +215,14 @@ def _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
 
 def _kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
             acc_ref, m_ref, l_ref, *, n_s: int, block_s: int, dim: int,
-            kv_bits: int):
+            kv_bits: int, window=None):
     # tab_ref is the scalar-prefetch block table: consumed by the K/V
     # index maps (page steering), never by the compute body
     del tab_ref
     si = pl.program_id(1)
     _flash_step(q_ref, k_ref, v_ref, ks_ref, pos_ref, acc_ref, m_ref,
                 l_ref, bi=pl.program_id(0), si=si, block_s=block_s,
-                dim=dim, kv_bits=kv_bits)
+                dim=dim, kv_bits=kv_bits, window=window)
 
     @pl.when(si == n_s - 1)
     def _epilogue():
@@ -233,7 +256,8 @@ def _partials_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
 
 def _launch(kernel, name, out_specs, out_shape, q, k_pool, v_pool,
-            block_tab, k_scale, v_scale, cur_pos, *, interpret, kv_bits):
+            block_tab, k_scale, v_scale, cur_pos, *, interpret, kv_bits,
+            window=None):
     """One decode launch over block-table-mapped tiles: grid, specs and
     operands shared by the normalized and the partials kernel."""
     b, kvh, g, d = q.shape
@@ -244,8 +268,11 @@ def _launch(kernel, name, out_specs, out_shape, q, k_pool, v_pool,
     bs = k_pool.shape[1]
     n_s = block_tab.shape[1]
 
+    # a window reaches the index map and the kernel body only where it
+    # is set, so a full layer compiles to the kernel without one
+    win = {} if window is None else {"window": window}
     kv_tile = pl.BlockSpec((1, bs, kvh, dp), functools.partial(
-        _kv_index, block_s=bs, n_s=n_s))
+        _kv_index, block_s=bs, n_s=n_s, **win))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -256,7 +283,7 @@ def _launch(kernel, name, out_specs, out_shape, q, k_pool, v_pool,
     )
     return pl.pallas_call(
         functools.partial(kernel, n_s=n_s, block_s=bs, dim=d,
-                          kv_bits=kv_bits),
+                          kv_bits=kv_bits, **win),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -272,7 +299,7 @@ def _launch(kernel, name, out_specs, out_shape, q, k_pool, v_pool,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "interpret", "kv_bits"))
+    jax.jit, static_argnames=("out_dtype", "interpret", "kv_bits", "window"))
 def decode_attention_tiles(
     q: jax.Array,          # (B, KV, G, D) float — one query token, GQA view
     k_pool: jax.Array,     # (pages, block_s, KV, D) int8/float (D/2 packed
@@ -285,6 +312,7 @@ def decode_attention_tiles(
     out_dtype=jnp.float32,
     interpret: bool = False,
     kv_bits: int = 8,
+    window: int | None = None,
 ):
     """Kernel core: fused one-token decode over block-table-mapped KV
     tiles.  The dense layout passes a free reshape of its cache plus the
@@ -300,7 +328,7 @@ def decode_attention_tiles(
         _kernel, "decode_attention", _head_block(kvh, g, d),
         jax.ShapeDtypeStruct((b, kvh, g, d), out_dtype),
         q, k_pool, v_pool, block_tab, k_scale, v_scale, cur_pos,
-        interpret=interpret, kv_bits=kv_bits)
+        interpret=interpret, kv_bits=kv_bits, window=window)
 
 
 def _dense_pool(k_cache, v_cache, block_s: int):
@@ -328,7 +356,8 @@ def _dense_pool(k_cache, v_cache, block_s: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_s", "out_dtype", "interpret", "kv_bits"))
+    static_argnames=("block_s", "out_dtype", "interpret", "kv_bits",
+                     "window"))
 def decode_attention_int8(
     q: jax.Array,        # (B, KV, G, D) float — one query token, GQA view
     k_cache: jax.Array,  # (B, S, KV, D) int8 (or float with scales == 1;
@@ -341,6 +370,7 @@ def decode_attention_int8(
     out_dtype=jnp.float32,
     interpret: bool = False,
     kv_bits: int = 8,
+    window: int | None = None,
 ):
     """Dense entry point: contiguous (B, S, KV, D) caches degenerate to
     the identity block table over a leading-axis reshape of the same
@@ -354,7 +384,8 @@ def decode_attention_int8(
     k_pool, v_pool, tab = _dense_pool(k_cache, v_cache, block_s)
     return decode_attention_tiles(
         q, k_pool, v_pool, tab, k_scale, v_scale, cur_pos,
-        out_dtype=out_dtype, interpret=interpret, kv_bits=kv_bits)
+        out_dtype=out_dtype, interpret=interpret, kv_bits=kv_bits,
+        window=window)
 
 
 def _head_block(kvh, g, last):

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _da
+from repro.kernels import expert_matmul as _em
 from repro.kernels import fake_quant as _fq
 from repro.kernels import prefill_attention as _pa
 from repro.kernels import quant_matmul as _qm
@@ -45,6 +46,19 @@ def quant_matmul(x, w_q, w_scale, act_scale, **kw):
 
 
 quant_matmul_ref = _ref.quant_matmul_ref
+
+
+def expert_matmul(x, w_q, w_scale, act_scale, tile_expert, n_live, **kw):
+    """Grouped int8 expert matmul (dropless MoE serving): rows grouped by
+    expert in ``block_m``-row tiles (``repro.models.moe.group_rows``),
+    each tile through its expert's int8 weights with per-expert static
+    activation scales.  x: (M, K); w_q: (E, K, N) int8; w_scale: (E, N);
+    act_scale: (E,); tile_expert: (M / block_m,); n_live: tiles in use."""
+    return _em.expert_matmul(x, w_q, w_scale, act_scale, tile_expert,
+                             n_live, interpret=_interpret(), **kw)
+
+
+expert_matmul_ref = _ref.expert_matmul_ref
 
 
 def decode_attention(q, k_cache, v_cache, k_scale, v_scale, cur_pos, **kw):

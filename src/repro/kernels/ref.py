@@ -24,8 +24,28 @@ def quant_matmul_ref(x, w_q, w_scale, act_scale, out_dtype=jnp.bfloat16,
     return (acc.astype(jnp.float32) * w_scale[None, :]).astype(out_dtype)
 
 
+def expert_matmul_ref(x, w_q, w_scale, act_scale, row_expert, sizes, *,
+                      bits=8, out_dtype=jnp.bfloat16):
+    """Oracle for kernels.expert_matmul, over the same grouped layout:
+    each row quantizes with its expert's scale, the groups (``sizes``
+    rows each, in expert order) multiply their own expert's int8 weights
+    with int32 accumulation (``lax.ragged_dot``), and each row dequantizes
+    by its expert's per-channel scale.  Rows past the groups read 0.
+
+    x: (M, K) float; w_q: (E, K, N) int8; w_scale: (E, N) f32 (already /
+    act scale); act_scale: (E,) f32; row_expert: (M,) int32; sizes: (E,).
+    """
+    levels = float(2 ** (bits - 1) - 1)
+    x_q = jnp.clip(jnp.round(x.astype(jnp.float32)
+                             * act_scale[row_expert][:, None]),
+                   -levels, levels).astype(jnp.int8)
+    acc = jax.lax.ragged_dot(x_q, w_q, sizes,
+                             preferred_element_type=jnp.int32)
+    return (acc.astype(jnp.float32) * w_scale[row_expert]).astype(out_dtype)
+
+
 def decode_attention_ref(q, k_cache, v_cache, k_scale, v_scale, cur_pos,
-                         out_dtype=jnp.float32, kv_bits=8):
+                         out_dtype=jnp.float32, kv_bits=8, window=None):
     """Oracle for kernels.decode_attention_int8: dequantize the cache,
     masked softmax over valid positions, GQA-grouped output.
 
@@ -34,7 +54,8 @@ def decode_attention_ref(q, k_cache, v_cache, k_scale, v_scale, cur_pos,
     k/v_scale: (KV,) dequant scales; cur_pos: valid cache length — a
     scalar (uniform batch) or a (B,) per-slot vector (continuous
     batching).  A row with cur_pos == 0 (empty cache / inactive slot)
-    returns zeros, matching the kernel.
+    returns zeros, matching the kernel.  ``window``: the query (at
+    cur_pos - 1) sees keys from cur_pos - window on.
     """
     b = q.shape[0]
     d = q.shape[-1]
@@ -46,7 +67,10 @@ def decode_attention_ref(q, k_cache, v_cache, k_scale, v_scale, cur_pos,
     qf = q.astype(jnp.float32) / jnp.sqrt(jnp.asarray(d, jnp.float32))
     s = jnp.einsum("bkgd,bskd->bkgs", qf, kf)
     pos = jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32).reshape(-1), (b,))
-    mask = jnp.arange(k_cache.shape[1])[None, :] < pos[:, None]  # (B, S)
+    k_pos = jnp.arange(k_cache.shape[1])[None, :]
+    mask = k_pos < pos[:, None]                                  # (B, S)
+    if window is not None:
+        mask &= k_pos >= pos[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, vf)

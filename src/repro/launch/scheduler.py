@@ -297,11 +297,13 @@ class SlotScheduler:
             raise ValueError(
                 "slot scheduler covers attention-only text stacks "
                 f"(got kinds={sorted(kinds)}, modality={cfg.modality})")
-        if wins != {None}:
-            raise ValueError(
-                "slot scheduler needs dense caches: SWA ring buffers drop "
-                f"absolute slots (got windows={sorted(map(str, wins))})")
         if cache_layout == "ring":
+            if wins != {None}:
+                raise ValueError(
+                    "slot scheduler needs absolute slots: the ring layout's "
+                    "SWA buffers drop them; serve windowed layers (windows="
+                    f"{sorted(map(str, wins))}) with cache_layout dense or "
+                    "paged, where the window is a mask")
             cache_layout = "dense"   # no windows here: ring == dense
         if cache_layout not in ("dense", "paged"):
             raise ValueError(
@@ -935,7 +937,7 @@ class SlotScheduler:
                             jnp.asarray(self._hist), jnp.asarray(nan_step))
                 with tel.span("decode.call"):
                     toks, emitted, self._cache, pos_d, active_d, keys_d, \
-                        hist, bad_d = self._decode(*args)
+                        hist, bad_d, routing = self._decode(*args)
                     del args        # the cache argument is donated
                 with tel.span("decode.fetch") as fetch:
                     toks = np.asarray(toks)
@@ -947,11 +949,13 @@ class SlotScheduler:
                     # (np.asarray of a device buffer is read-only)
                     self._hist = np.array(hist)
                     self._slot_keys = np.array(keys_d)
+                    routing = {k: int(v) for k, v in routing.items()}
                 with tel.span("decode.collect"):
                     if self._emit_w == 1:
                         # rs.pos still holds the block's start positions
                         block.attrs.update(self._kv_tiles(rs.pos, ran,
                                                           emitted, bad))
+                    block.attrs.update(routing)
                     block.attrs["kept"] = self._collect(
                         rs, ran, toks, emitted, pos_new, active_new, bad,
                         fetch.t1, retire)
@@ -986,7 +990,8 @@ class SlotScheduler:
         for each step it emits, plus the step a non-finite logit froze
         it in, and at step j attends its first ``pos0 + j + 1`` keys.
         Greedy blocks only: a speculative verify runs the prefill
-        kernel."""
+        kernel.  Counted as a full layer counts them: a windowed layer
+        also skips the tiles left of its window."""
         j = np.arange(self.block_steps)
         steps = emitted.sum(axis=1) + bad
         live = (j < steps[:, None]) & ran[:, None]

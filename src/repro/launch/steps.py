@@ -70,7 +70,10 @@ class TrainHParams:
 def make_calibrate_step(model, cfg, policy: A.QuantPolicy):
     def calibrate_step(params, qparams, batch):
         ctx = A.make_ctx("calibrate", policy, qparams)
-        model.hidden(params, batch, ctx, remat=False)
+        h, _ = model.hidden(params, batch, ctx, remat=False)
+        # an untied readout is a quantized Dense: it observes the final
+        # hidden states like every other layer its input
+        model.readout_fn(params, ctx)(h)
         merged = dict(qparams)
         for path, obs in ctx.updates.items():
             if A.is_kv_path(path):  # KV observer entry: replace wholesale
@@ -359,8 +362,7 @@ def make_slot_decode_loop(model, cfg, policy: A.QuantPolicy,
 
     def slot_decode_loop(serve_params, qparams, tok0, cache, pos0, active0,
                          key=None):
-        toks, emitted, cache, pos, active, key, _, _ = inner(
-            serve_params, qparams, tok0, cache, pos0, active0, key)
-        return toks, emitted, cache, pos, active, key
+        out = inner(serve_params, qparams, tok0, cache, pos0, active0, key)
+        return out[:6]
 
     return slot_decode_loop

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import api as A
+from repro.models.moe import routing_stats
 
 
 class DecodeState(NamedTuple):
@@ -524,8 +525,12 @@ def make_strategy_slot_loop(model, cfg, policy: A.QuantPolicy,
         runs share one executable.
 
     Returns ``(toks (B, n_steps * W), emitted (B, n_steps * W), cache,
-    pos, active, key, hist, bad)`` with W = ``emit_width``; lane j of
-    step i sits at column i * W + j.  Under speculation emissions are
+    pos, active, key, hist, bad, routing)`` with W = ``emit_width``; lane
+    j of step i sits at column i * W + j.  ``routing`` holds the block's
+    routing counters (``repro.models.moe.routing_stats``:
+    ``expert_rows``, ``expert_rows_max``, ``experts_live``), each summed
+    over the steps and the layers, for an unrolled stack with MoE layers;
+    it is empty for any other stack.  Under speculation emissions are
     ragged WITHIN a window, so consumers skip un-emitted lanes rather
     than stopping at the first (the scheduler does).  All shapes are
     fixed by (max_slots, cache_len, n_steps, W): one compiled executable
@@ -534,6 +539,10 @@ def make_strategy_slot_loop(model, cfg, policy: A.QuantPolicy,
     """
     _check_attn_only(cfg, "slot decode")
     w = strategy.emit_width
+    # a scanned stack traces its layers inside an inner scan, out of the
+    # counters' reach
+    counted = (not cfg.scan_layers
+               and any(cfg.ffn_kind(i) == "moe" for i in range(cfg.n_layers)))
 
     def slot_loop(serve_params, qparams, tok0, cache, pos0, active0,
                   key=None, hist=None, nan_step=None):
@@ -557,8 +566,11 @@ def make_strategy_slot_loop(model, cfg, policy: A.QuantPolicy,
             if cache_len is not None:
                 active = active & (pos + w <= cache_len)
             drafts = strategy.propose(tok, pos, hist)
-            logits, cache = strategy.verify(serve_params, qparams, tok,
-                                            drafts, cache, pos, active)
+            with routing_stats() as sink:
+                logits, cache = strategy.verify(serve_params, qparams, tok,
+                                                drafts, cache, pos, active)
+            stats = ({k: sum(d[k] for d in sink) for k in sink[0]}
+                     if counted else {})
             # injected fault: scheduled slots' logits turn NaN here, then
             # flow through the same detection as a real model fault
             hit = (nan_step == step_i) & active
@@ -598,19 +610,20 @@ def make_strategy_slot_loop(model, cfg, policy: A.QuantPolicy,
             pos = pos + n_acc
             cache = _rollback(cache, pos)
             return ((nxt, cache, pos, active, key, hist), bad_acc), \
-                (toks, emitted)
+                (toks, emitted, stats)
 
         active0 = jnp.asarray(active0, bool)
         if hist is None:
             hist = jnp.zeros((pos0.shape[0], 0), jnp.int32)
         carry0 = ((jnp.asarray(tok0, jnp.int32), cache, pos0, active0, key,
                    hist), jnp.zeros(active0.shape, bool))
-        ((tok, cache, pos, active, key, hist), bad), (toks, emitted) = \
-            jax.lax.scan(body, carry0, jnp.arange(n_steps, dtype=jnp.int32))
+        ((tok, cache, pos, active, key, hist), bad), (toks, emitted, stats) \
+            = jax.lax.scan(body, carry0, jnp.arange(n_steps, dtype=jnp.int32))
         # (n_steps, B, W) -> (B, n_steps * W): lane j of step i at i*W+j
         b = pos.shape[0]
         toks = jnp.moveaxis(toks, 0, 1).reshape(b, n_steps * w)
         emitted = jnp.moveaxis(emitted, 0, 1).reshape(b, n_steps * w)
-        return toks, emitted, cache, pos, active, key, hist, bad
+        routing = {k: jnp.sum(v) for k, v in stats.items()}
+        return toks, emitted, cache, pos, active, key, hist, bad, routing
 
     return slot_loop

@@ -293,6 +293,7 @@ class Attention(Module):
         path: str,
         window: int | None = None,
         rope_base: float = 10000.0,
+        yarn=None,
         causal: bool = True,
         cross: bool = False,
         dtype=jnp.bfloat16,
@@ -306,6 +307,7 @@ class Attention(Module):
         self.groups = n_heads // n_kv_heads
         self.window = window
         self.rope_base = rope_base
+        self.yarn = yarn
         self.causal = causal
         self.cross = cross
         self.path = path
@@ -427,11 +429,13 @@ class Attention(Module):
         return q, k, v
 
     def _rope(self, q, k, q_positions, k_positions):
-        cos_q, sin_q = rotary_angles(q_positions, self.head_dim, self.rope_base)
+        cos_q, sin_q = rotary_angles(q_positions, self.head_dim,
+                                     self.rope_base, self.yarn)
         b, s, kvh, g, d = q.shape
         qf = q.reshape(b, s, kvh * g, d)
         qf = apply_rotary(qf, cos_q, sin_q)
-        cos_k, sin_k = rotary_angles(k_positions, self.head_dim, self.rope_base)
+        cos_k, sin_k = rotary_angles(k_positions, self.head_dim,
+                                     self.rope_base, self.yarn)
         k = apply_rotary(k, cos_k, sin_k)
         return qf.reshape(b, s, kvh, g, d), k
 
@@ -664,11 +668,11 @@ class Attention(Module):
 
         With an int8 cache the new K/V quantize on append using the scales
         stored in the cache (written at prefill), so decode needs no
-        threshold state.  The non-windowed int8 path can run the fused
-        Pallas flash-decode kernel (policy.use_pallas), which streams the
-        cache's kernel-view tiles (block-table-mapped for paged) and
-        dequantizes in VMEM; otherwise the cache dequantizes into the jnp
-        reference attention.
+        threshold state.  The int8 path can run the fused Pallas
+        flash-decode kernel (policy.use_pallas), which streams the cache's
+        kernel-view tiles (block-table-mapped for paged) and dequantizes
+        in VMEM, a windowed layer's tiles from its window's start only;
+        otherwise the cache dequantizes into the jnp reference attention.
         """
         b, s, _ = x.shape
         q, k, v = self._qkv(params, x, ctx, kv_src=None if not self.cross else memory)
@@ -728,15 +732,17 @@ class Attention(Module):
                 valid = cur_pos + 1
             use_kernel = (
                 cache.quantized
-                and self.window is None
                 and ctx is not None
                 and ctx.policy.use_pallas
             )
             if use_kernel:
                 from repro.kernels import ops as kops
 
+                # a windowed layer's cache holds every position: the
+                # kernel masks, and skips the tiles left of, its window
                 o = kops.decode_attention_view(
                     q[:, 0], upd.kernel_view(), *upd.scales(), valid,
+                    window=self.window,
                 )[:, None].astype(x.dtype)
             else:
                 k_eff, v_eff = upd.dequantize(*upd.dense_view())
@@ -861,7 +867,6 @@ class Attention(Module):
 
         use_kernel = (
             cache.quantized
-            and self.window is None
             and ctx is not None
             and ctx.policy.use_pallas
         )
@@ -875,7 +880,7 @@ class Attention(Module):
                 kv_len = jnp.where(slot_mask, kv_len, 0)
             o = kops.prefill_attention_view(
                 q, upd.kernel_view(), *upd.scales(), pos_vec, kv_len,
-                causal=True, window=None,
+                causal=True, window=self.window,
             ).astype(x.dtype)
         else:
             k_eff, v_eff = upd.dequantize(*upd.dense_view())

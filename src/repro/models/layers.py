@@ -6,6 +6,7 @@ way the paper folds batch-norm into conv weights (§3.1.2, eqs. 10-11).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -92,12 +93,47 @@ class Embedding(Module):
         return logits
 
 
-def rotary_angles(positions: jax.Array, head_dim: int, base: float = 10000.0):
-    """(..., S) int positions -> (cos, sin) of shape (..., S, head_dim/2)."""
+def yarn_inv_freq(head_dim: int, base: float, yarn) -> np.ndarray:
+    """YaRN's (head_dim/2,) inverse frequencies, as transformers'
+    ``_compute_yarn_parameters`` computes them with ``truncate`` true:
+    dims that turn fewer than ``beta_slow`` times over ``original_max``
+    positions are interpolated (divided by ``factor``), those that turn
+    more than ``beta_fast`` times are kept, and a linear ramp blends the
+    dims between."""
     half = head_dim // 2
-    freqs = 1.0 / (base ** (np.arange(0, half, dtype=np.float32) / half))
+
+    def dim_at(rotations):
+        return (head_dim * math.log(yarn.original_max
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(dim_at(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_at(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extrap = 1.0 / (base ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                             / head_dim))
+    interp = extrap / yarn.factor
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (interp * ramp + extrap * (1.0 - ramp)).astype(np.float32)
+
+
+def rotary_angles(positions: jax.Array, head_dim: int, base: float = 10000.0,
+                  yarn=None):
+    """(..., S) int positions -> (cos, sin) of shape (..., S, head_dim/2).
+    ``yarn`` (a ``configs.base.Yarn``) swaps in YaRN's frequencies and
+    scales cos and sin by its attention factor."""
+    half = head_dim // 2
+    if yarn is None:
+        freqs = 1.0 / (base ** (np.arange(0, half, dtype=np.float32) / half))
+    else:
+        freqs = yarn_inv_freq(head_dim, base, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = jnp.float32(yarn.attention_factor)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

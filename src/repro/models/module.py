@@ -162,7 +162,8 @@ class ExpertDense(Module):
     """Batched expert weights (E, in, out) for MoE layers.
 
     Quantized in vector mode with per-(expert, out-channel) thresholds —
-    the natural generalization of the paper's per-filter thresholds.
+    the natural generalization of the paper's per-filter thresholds — and
+    one activation threshold per expert.
     """
 
     def __init__(
@@ -200,8 +201,12 @@ class ExpertDense(Module):
         ).astype(self.dtype)
         return {"w": w}
 
-    def __call__(self, params: dict, x: jax.Array, ctx=None) -> jax.Array:
-        """x: (E, C, in) -> (E, C, out); einsum over per-expert blocks."""
+    def __call__(self, params: dict, x: jax.Array, ctx=None, *,
+                 groups=None) -> jax.Array:
+        """x: (M, in) rows grouped by expert as ``groups`` lays them out
+        (``repro.models.moe.group_rows``) -> (M, out); without ``groups``,
+        the training forward's capacity layout (..., E, C, in) ->
+        (..., E, C, out)."""
         from repro.core import api
 
-        return api.expert_dense_forward(self, params, x, ctx)
+        return api.expert_dense_forward(self, params, x, ctx, groups)

@@ -49,7 +49,7 @@ class Block(Module):
             self.attn = Attention(
                 d, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                 path=f"{path}/attn", window=window, rope_base=cfg.rope_base,
-                causal=cfg.causal, dtype=dt,
+                yarn=cfg.rope_yarn(layer_idx), causal=cfg.causal, dtype=dt,
                 q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
             )
         if self.kind in ("mamba", "hybrid"):
@@ -76,7 +76,8 @@ class Block(Module):
             if self.ffn_kind == "moe":
                 self.ffn = MoE(d, cfg.d_ff, cfg.n_experts, cfg.top_k,
                                path=f"{path}/moe", dtype=dt,
-                               capacity_factor=cfg.capacity_factor)
+                               capacity_factor=cfg.capacity_factor,
+                               activation=cfg.mlp_activation)
             elif self.ffn_kind == "gelu":
                 self.ffn = GeluMLP(d, cfg.d_ff, path=f"{path}/mlp", dtype=dt,
                                    activation=cfg.mlp_activation)
@@ -125,7 +126,10 @@ class Block(Module):
         if self.ffn_kind != "none":
             h = self.ffn_norm(params["ffn_norm"], x)
             if self.ffn_kind == "moe":
-                y, aux = self.ffn(params["ffn"], h, ctx)
+                # training dispatches by capacity per sequence; the
+                # calibration pass observes every routed row
+                y, aux = self.ffn(params["ffn"], h, ctx, dropless=(
+                    ctx is not None and ctx.mode == "calibrate"))
             else:
                 y = self.ffn(params["ffn"], h, ctx)
             x = x + y
@@ -188,7 +192,12 @@ class Block(Module):
         if self.ffn_kind != "none":
             h = self.ffn_norm(params["ffn_norm"], x)
             if self.ffn_kind == "moe":
-                y, _ = self.ffn(params["ffn"], h, ctx)
+                # a chunk's padding past each request's length routes to
+                # no expert
+                mask = None if lengths is None else (
+                    q_offset + jnp.arange(x.shape[1])[None, :]
+                    < jnp.asarray(lengths, jnp.int32).reshape(-1, 1))
+                y, _ = self.ffn(params["ffn"], h, ctx, row_mask=mask)
             else:
                 y = self.ffn(params["ffn"], h, ctx)
             x = x + y
@@ -267,7 +276,8 @@ class Block(Module):
         if self.ffn_kind != "none":
             h = self.ffn_norm(params["ffn_norm"], x)
             if self.ffn_kind == "moe":
-                y, _ = self.ffn(params["ffn"], h, ctx)
+                # inactive slots route to no expert
+                y, _ = self.ffn(params["ffn"], h, ctx, row_mask=slot_mask)
             else:
                 y = self.ffn(params["ffn"], h, ctx)
             x = x + y
@@ -294,7 +304,8 @@ class Block(Module):
         if self.ffn_kind != "none":
             h = self.ffn_norm(params["ffn_norm"], x)
             if self.ffn_kind == "moe":
-                y, _ = self.ffn(params["ffn"], h, ctx)
+                # inactive slots route to no expert
+                y, _ = self.ffn(params["ffn"], h, ctx, row_mask=slot_mask)
             else:
                 y = self.ffn(params["ffn"], h, ctx)
             x = x + y
@@ -323,6 +334,10 @@ class Stack(Module):
         self.path = path
         self.cross = cross
         self.n_layers = n_layers or cfg.n_layers
+        if cfg.scan_layers and cfg.global_yarn is not None:
+            raise ValueError(
+                "YaRN on the global layers needs the unrolled stack: the "
+                "scanned template carries one rotary (scan_layers=False)")
         if cfg.scan_layers:
             # one template block; layer differences via force_full flags
             self.template = Block(cfg, self._template_idx(), path=f"{path}/layers",

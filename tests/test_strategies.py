@@ -273,7 +273,8 @@ class TestSpeculativeStub:
         """With a periodic history every draft matches: each window emits
         draft_k + 1 tokens — the speculation payoff — and the emitted
         stream is exactly the greedy continuation 4,5,6,7,0,1,..."""
-        toks, emitted, _, pos, active, _, hist, _ = self._loop(n_steps=2, k=4)
+        toks, emitted, _, pos, active, _, hist, _, _ = self._loop(
+            n_steps=2, k=4)
         toks, emitted = np.asarray(toks), np.asarray(emitted)
         assert emitted.all()                    # every lane accepted
         want = [(4 + i) % self.CYCLE for i in range(10)]
@@ -286,7 +287,7 @@ class TestSpeculativeStub:
         """EOS inside an accepted window: the EOS lane is emitted, later
         lanes in the window are cut, the slot freezes, and its position
         only advances past what was emitted."""
-        toks, emitted, _, pos, active, _, _, _ = self._loop(
+        toks, emitted, _, pos, active, _, _, _, _ = self._loop(
             n_steps=2, k=4, eos_id=6)
         toks, emitted = np.asarray(toks), np.asarray(emitted)
         # window 1 would emit 4,5,6,7,0 -> cut after the EOS (6)
@@ -297,7 +298,7 @@ class TestSpeculativeStub:
     def test_capacity_guard_freezes_before_partial_window(self):
         """A slot without room for a WHOLE window freezes rather than
         clamp-writing a partial one."""
-        toks, emitted, _, pos, active, _, _, _ = self._loop(
+        toks, emitted, _, pos, active, _, _, _, _ = self._loop(
             n_steps=2, k=4, cache_len=17)
         # pos 10 + window 5 <= 17 fits once; a second window would need 20
         assert np.asarray(emitted)[0].tolist() == [True] * 5 + [False] * 5

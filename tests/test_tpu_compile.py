@@ -5,7 +5,9 @@ refuses (block shapes that are neither (8, 128)-aligned nor the whole
 array, VMEM over-use).  These tests hand the real kernels, with
 ``interpret=False``, to the TPU compiler for a v5e chip that is described
 rather than attached, at the widths of smollm-135m (d_model 576, 3 KV
-heads of 64, d_ff 1536, vocab 49152).  Nothing runs: a pass means the
+heads of 64, d_ff 1536, vocab 49152), and the Mellum2 kernels at its
+widths (d_model 2304, 64 experts of width 896, 4 KV heads of 128, a
+1024-key window).  Nothing runs: a pass means the
 chip's compiler accepts the kernel, not that its results are right (the
 interpret-mode parity tests cover that).
 
@@ -19,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import decode_attention as DA
+from repro.kernels import expert_matmul as EM
 from repro.kernels import prefill_attention as PA
 from repro.kernels import quant_matmul as QM
 
@@ -129,3 +132,47 @@ def test_prefill_attention_paged_compiles(one_chip):
                     ((b, S // PAGE), jnp.int32), ((KV,), jnp.float32),
                     ((KV,), jnp.float32), ((b,), jnp.int32),
                     ((b,), jnp.int32)) >= 1
+
+
+@pytest.mark.parametrize("assignments", [512, 128],
+                         ids=["decode_64_slots", "admission_chunk"])
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)],
+                         ids=["gate_up", "down"])
+def test_expert_matmul_compiles(one_chip, assignments, k, n):
+    """Mellum2's grouped expert projections: top-8 assignments of 64 slots
+    (a decode step) or of one 16-token chunk, laid out in 16-row tiles
+    with room for every routing (``models/moe.py:group_rows``)."""
+    e, bm = 64, 16
+    tiles = assignments // bm + e
+
+    def f(x, w, ws, sa, te, nl):
+        return EM.expert_matmul(x, w, ws, sa, te, nl, block_m=bm)
+
+    assert _compile(f, one_chip, ((tiles * bm, k), jnp.bfloat16),
+                    ((e, k, n), jnp.int8), ((e, n), jnp.float32),
+                    ((e,), jnp.float32), ((tiles,), jnp.int32),
+                    ((), jnp.int32)) >= 1
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_decode_attention_window_compiles(one_chip, layout):
+    """A windowed layer's decode at Mellum2's head shape (4 KV heads of 128,
+    8 query heads each) over a 768-key cache, window 1024."""
+    b, kv, g, d, s, win = 64, 4, 8, 128, 768, 1024
+    scalars = [((kv,), jnp.float32), ((kv,), jnp.float32), ((b,), jnp.int32)]
+    q = ((b, kv, g, d), jnp.float32)
+    if layout == "dense":
+        def f(q, k, v, ks, vs, pos):
+            return DA.decode_attention_int8(q, k, v, ks, vs, pos,
+                                            interpret=False, window=win)
+
+        cache = ((b, s, kv, d), jnp.int8)
+        shapes = [q, cache, cache, *scalars]
+    else:
+        def f(q, k, v, tab, ks, vs, pos):
+            return DA.decode_attention_tiles(q, k, v, tab, ks, vs, pos,
+                                             interpret=False, window=win)
+
+        cache = ((b * s // PAGE, PAGE, kv, d), jnp.int8)
+        shapes = [q, cache, cache, ((b, s // PAGE), jnp.int32), *scalars]
+    assert _compile(f, one_chip, *shapes) >= 1
